@@ -30,6 +30,7 @@ hardware-independent cost driver: QPS ∝ 1/dists at fixed hardware) and
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import time
 
@@ -138,11 +139,11 @@ def adc_throughput(ds, n_codes: int = 4096, batch: int = 64,
         if kind == "pq4":
             codes = qz.pq4_encode(st.codebooks, jnp.asarray(base))
             tables = qz.pq4_query_tables(st.codebooks, jnp.asarray(q), ds.metric)
-            fn = qz.pq4_make_dist_fn(codes, m)
+            fn = functools.partial(qz.pq4_make_dist_fn(m), codes)
         else:
             codes = qz.pq_encode(st.codebooks, jnp.asarray(base))
             tables = qz.pq_query_tables(st.codebooks, jnp.asarray(q), ds.metric)
-            fn = qz.pq_make_dist_fn(codes, m)
+            fn = functools.partial(qz.pq_make_dist_fn(m), codes)
         jfn = jax.jit(fn)
         jfn(tables, ids).block_until_ready()            # compile
         t0 = time.perf_counter()

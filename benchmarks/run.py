@@ -48,6 +48,8 @@ def main() -> None:
                          "the crash-point save/load matrix; writes "
                          "BENCH_serving_smoke.json — artifact-only)")
     args, _ = ap.parse_known_args()
+    from repro.launch.compile_cache import use_compile_cache
+    print(f"compile cache: {use_compile_cache()}")
     if args.bin_smoke:
         from benchmarks import qps_recall
         qps_recall.bin_smoke()

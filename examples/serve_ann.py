@@ -13,6 +13,7 @@ from repro.core.tune import tune_early_term
 from repro.core.types import (BuildConfig, IndexConfig, QuantConfig,
                               SearchConfig)
 from repro.data.vectors import make_dataset
+from repro.launch.serve import device_label
 from repro.serve import Request, SearchEngine, serve_loop
 
 
@@ -50,7 +51,7 @@ def main():
     per_q = st.mean_lat_ms * st.n_requests / max(st.n_queries, 1)
     print(f"served {report.n_served} queries | "
           f"recall@10={report.recall_at_k:.3f} | "
-          f"mean latency {per_q:.2f} ms/q (CPU interpret) | "
+          f"mean latency {per_q:.2f} ms/q ({device_label()}) | "
           f"p95 {report.lat_p95_ms:.2f} ms/batch")
     print("engine:", st.summary())
     assert report.n_served == 150, report.n_served

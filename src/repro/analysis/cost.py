@@ -141,6 +141,8 @@ def bindings(w: Workload, **over) -> Dict[str, object]:
         "nlist": nlist, "fill": fill,
         "max_len": _pad_to(int(math.ceil(fill)), w.list_pad),
         "P": min(w.nprobe, nlist),
+        # list-scan tile: kernels/ivf_scan.list_tile's largest choice
+        "TL": min(512, _pad_to(int(math.ceil(fill)), w.list_pad)),
         "lg": _lg,
     }
     ns.update(over)
@@ -163,8 +165,9 @@ class KernelCost:
     note: str = ""
 
 
-# The merge term of the fused traversal kernels: a bitonic-style sort of
-# the (queue + candidates) region costs ~x*lg(x)^2 compare-exchanges.
+# The merge term of the fused traversal step: a bitonic-style sort of the
+# (queue + candidates) region costs ~x*lg(x)^2 compare-exchanges (run in
+# XLA after the gather kernel).
 _SORT = "(L + C) * lg(L + C)**2"
 # The per-list partial top-L of the IVF scans.
 _TOPL = "max_len * lg(L)"
@@ -191,17 +194,17 @@ KERNEL_COSTS: Dict[str, KernelCost] = {
         hbm_bytes="4.0*(Q*C*nw + Q*nw + 2.0*Q*C)",
         cands="Q*C",
         note="XOR + SWAR popcount per u32 word; nw = ceil(d/32) words"),
-    # -- ADC kernels (gather-as-matmul: m*K MACs per code, DESIGN.md §13) -
+    # -- ADC kernels (one-hot table select: m*K ops per code, DESIGN.md §13)
     "pq_adc": KernelCost(
         flops="3.0*Q*C*m*K",
         hbm_bytes="1.0*Q*C*m + 4.0*(Q*m*K + 2.0*Q*C)",
         cands="Q*C",
-        note="one-hot MXU expansion: K=256 MACs per code, not a table read"),
+        note="one-hot select over the (K, m) table: K=256 ops per code"),
     "pq4_adc": KernelCost(
         flops="3.0*Q*C*m*K4",
         hbm_bytes="0.5*Q*C*m + 4.0*(Q*m*K4 + 2.0*Q*C)",
         cands="Q*C",
-        note="K=16 one-hot + nibble-packed codes: 16x fewer MACs than pq8"),
+        note="K=16 one-hot + nibble-packed codes: 16x fewer ops than pq8"),
     # -- fused beam-expansion kernels (gather+dist+merge, DESIGN.md §2) ---
     "fused_expand": KernelCost(
         flops="Q*(3.0*C*D + %s)" % _SORT,

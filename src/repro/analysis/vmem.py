@@ -36,6 +36,7 @@ DIMS: Dict[str, int] = {
     "d": 1024, "tq": 128, "tb": 128, "m": 64, "K": 256, "mh": 32,
     "C": 4096, "T": 1024, "W": 16, "n_beam": 16, "L": 1024,
     "max_len": 4096, "nw": 64, "Q": 8, "B": 8, "P": 8, "nlist": 64,
+    "TL": 512, "K4": 16,
 }
 DEFAULT_DIM = 128
 DTYPE_BYTES = {"float32": 4, "int32": 4, "uint32": 4, "float64": 8,
@@ -104,7 +105,7 @@ def estimate(tree: Tree) -> List[KernelEstimate]:
         notes: List[str] = []
 
         # BlockSpecs appear inline in the wrapper, or behind module-level
-        # helpers the wrapper calls (traverse_step's _out_specs(T, W)).
+        # helpers the wrapper calls.
         spec_scopes = [fn]
         for call in ast.walk(fn):
             if isinstance(call, ast.Call) and \

@@ -22,7 +22,11 @@ FAMILY = "anns"
 SHAPES = ("glove_like", "deep_like", "t2i_like", "bigann_like")
 
 # (dim, metric, build, search) tuned on the synthetic analogues to reach
-# recall@10 >= 0.95 (benchmarks/qps_recall.py)
+# recall@10 >= 0.95 (benchmarks/qps_recall.py). deep_like is sized for its
+# 1M-row deployment (chip_smoke.py): recall@10 at L=64 fell from the 50k
+# figure to 0.857 at 100k rows (CPU, 300 queries), and L=192 with
+# patience 32 measured 0.779 at 1M rows (one TPU v5e, 770 queries), so the
+# queue is doubled again.
 _CONFIGS = {
     "glove_like": dict(
         dim=100, metric="ip",
@@ -35,7 +39,7 @@ _CONFIGS = {
         build=BuildConfig(M=24, knn_k=32, select_rule="alpha", alpha=1.2,
                           search_passes=1, refine_iters=1, refine_cands=64,
                           reorder="mst"),
-        search=SearchConfig(L=64, k=10, early_term=True, et_patience=16)),
+        search=SearchConfig(L=384, k=10, early_term=True, et_patience=48)),
     "t2i_like": dict(
         dim=200, metric="ip",
         build=BuildConfig(M=32, knn_k=48, select_rule="alpha", alpha=1.2,
@@ -53,9 +57,13 @@ _CONFIGS = {
 
 # IVF-PQ presets: pq_m must divide dim; nprobe/L tuned for the re-ranked
 # pipeline (candidate recall == final recall with rerank=0 => full queue).
+# deep_like probes for its 1M-row deployment: with nlist = sqrt(n), a fixed
+# nprobe covers a shrinking share of the lists as n grows, and nprobe=24
+# measured recall@10 0.876 at 50k but 0.798 at 400k; nprobe=96 with L=256
+# measured 0.978 at 400k (CPU, 300 queries).
 _IVF_CONFIGS = {
     "glove_like": dict(dim=100, metric="ip", pq_m=20, nprobe=32, L=192),
-    "deep_like": dict(dim=96, metric="ip", pq_m=16, nprobe=24, L=128),
+    "deep_like": dict(dim=96, metric="ip", pq_m=16, nprobe=96, L=256),
     "t2i_like": dict(dim=200, metric="ip", pq_m=20, nprobe=32, L=192),
     "bigann_like": dict(dim=128, metric="l2", pq_m=16, nprobe=32, L=192),
 }

@@ -56,15 +56,36 @@ def brute_force_knn(db: jnp.ndarray, k: int, metric: str, chunk: int = 256
     return ids.reshape(n_pad, k)[:n], dists.reshape(n_pad, k)[:n]
 
 
-def _gather_dists(db: jnp.ndarray, ids: jnp.ndarray, metric: str) -> jnp.ndarray:
-    """Per-row distances d(db[i], db[ids[i, j]]) with -1 masked to inf."""
-    vecs = db[jnp.maximum(ids, 0)]                          # (n, C, d)
+def _row_dists(db: jnp.ndarray, rows: jnp.ndarray, ids: jnp.ndarray,
+               metric: str) -> jnp.ndarray:
+    """d(db[rows[i]], db[ids[i, j]]) with -1 masked to inf. (nc, C)."""
+    vecs = db[jnp.maximum(ids, 0)]                          # (nc, C, d)
+    base = db[rows]
     if metric == "l2":
-        diff = vecs - db[:, None, :]
+        diff = vecs - base[:, None, :]
         out = jnp.sum(diff * diff, axis=-1)
     else:
-        out = -jnp.einsum("ncd,nd->nc", vecs, db)
+        out = -jnp.einsum("ncd,nd->nc", vecs, base)
     return jnp.where(ids >= 0, out, jnp.inf)
+
+
+def map_rows(f, n: int, chunk: int):
+    """Apply f(rows (c,)) -> per-row outputs over all n rows, `chunk` rows
+    at a time (lax.map), so the gathered (chunk, C, d) working set is
+    bounded independently of n. A tail chunk repeats the last row and its
+    outputs are dropped; with n <= chunk it is one call over arange(n)."""
+    c = min(chunk, n)
+    n_chunks = -(-n // c)
+    rows = jnp.minimum(jnp.arange(n_chunks * c, dtype=jnp.int32), n - 1)
+    out = jax.lax.map(f, rows.reshape(n_chunks, c))
+    return jax.tree.map(lambda a: a.reshape((n_chunks * c,) + a.shape[2:])[:n],
+                        out)
+
+
+# Bytes of gathered neighbor vectors one NN-descent chunk may hold: bounds
+# a round's working set independently of n (a 1M x 384-candidate x 96-d
+# round gathered at once is ~147 GB, far beyond one chip's 16 GB HBM).
+GATHER_BUDGET = 1 << 30
 
 
 @functools.partial(jax.jit, static_argnames=("k", "metric", "rounds", "sample"))
@@ -73,24 +94,38 @@ def nn_descent(db: jnp.ndarray, k: int, metric: str, rounds: int = 6,
     """Approximate kNN graph by fixed-round NN-descent.
 
     Candidates per round: current neighbors ∪ (first `sample` neighbors of
-    each neighbor). Distances are the (n, C, d) batched gather-einsum — the
-    same Q-to-B workload as search, so the MXU path applies at build time.
+    each neighbor). Distances are the (rows, C, d) batched gather-einsum —
+    the same Q-to-B workload as search, so the MXU path applies at build
+    time. Each round reads the previous round's whole graph and updates
+    rows chunk by chunk (GATHER_BUDGET), so the result does not depend on
+    the chunking.
     """
     n, d = db.shape
+    chunk = max(1, GATHER_BUDGET // (4 * d * k * max(sample, 1)))
     key = jax.random.PRNGKey(seed)
     ids = jax.random.randint(key, (n, k), 0, n, dtype=jnp.int32)
     # avoid trivial self edges
     ids = jnp.where(ids == jnp.arange(n, dtype=jnp.int32)[:, None], (ids + 1) % n, ids)
-    dists = _gather_dists(db, ids, metric)
-    ids, dists = _merge_topk(ids, dists, ids, dists, k)  # dedupe the random init
+
+    def init_rows(rows):
+        r_ids = ids[rows]
+        r_d = _row_dists(db, rows, r_ids, metric)
+        return _merge_topk(r_ids, r_d, r_ids, r_d, k)  # dedupe the random init
+
+    ids, dists = map_rows(init_rows, n, chunk)
 
     def round_fn(carry, _):
         ids, dists = carry
-        nbr2 = ids[jnp.maximum(ids, 0)][:, :, :sample].reshape(n, -1)   # (n, k*sample)
-        nbr2 = jnp.where(nbr2 == jnp.arange(n, dtype=jnp.int32)[:, None], -1, nbr2)
-        d2 = _gather_dists(db, nbr2, metric)
-        ids, dists = _merge_topk(ids, dists, nbr2, d2, k)
-        return (ids, dists), None
+
+        def rows_fn(rows):
+            r_ids = ids[rows]
+            nbr2 = ids[jnp.maximum(r_ids, 0)][:, :, :sample].reshape(
+                rows.shape[0], -1)                          # (c, k*sample)
+            nbr2 = jnp.where(nbr2 == rows[:, None], -1, nbr2)
+            d2 = _row_dists(db, rows, nbr2, metric)
+            return _merge_topk(r_ids, dists[rows], nbr2, d2, k)
+
+        return map_rows(rows_fn, n, chunk), None
 
     (ids, dists), _ = jax.lax.scan(round_fn, (ids, dists), None, length=rounds)
     return ids, dists

@@ -27,8 +27,12 @@ shard-local results; plain KBest always owns the whole corpus.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 import json
+import logging
+import time
 import warnings
 from pathlib import Path
 from typing import Optional, Tuple
@@ -47,6 +51,8 @@ from repro.core.distance import normalize, pairwise
 from repro.core.refine import refine_graph
 from repro.core.types import IndexConfig, SearchConfig
 
+log = logging.getLogger(__name__)
+
 
 class KBest:
     def __init__(self, config: IndexConfig):
@@ -54,7 +60,7 @@ class KBest:
         self.db: Optional[jnp.ndarray] = None        # (n, d) f32 (normalized if cosine)
         self.graph: Optional[jnp.ndarray] = None     # (n, M) i32
         self.entry: int = 0
-        self.order: Optional[np.ndarray] = None      # new->old id map
+        self.order: Optional[jnp.ndarray] = None     # (n,) i32 new->old id map
         # quantization state
         self.pq: Optional[qz.PQState] = None
         self.pq_codes: Optional[jnp.ndarray] = None
@@ -63,7 +69,15 @@ class KBest:
         self.bin: Optional[qz.BinState] = None
         self.bin_codes: Optional[jnp.ndarray] = None
         self.ivf: Optional[ivf_mod.IVFState] = None
-        self._dist_fns = {}
+        # wall seconds of each build stage of the last add() (set-up cost)
+        self.build_s: dict = {}
+
+    @contextlib.contextmanager
+    def _stage(self, name: str):
+        t0 = time.perf_counter()
+        yield
+        self.build_s[name] = time.perf_counter() - t0
+        log.info("build %s: %.1f s", name, self.build_s[name])
 
     # ------------------------------------------------------------------ add
     def add(self, x: np.ndarray) -> "KBest":
@@ -78,38 +92,49 @@ class KBest:
             x = normalize(x)
         metric = "ip" if cfg.metric == "cosine" else cfg.metric
 
+        self.build_s = {}
         if cfg.index_type == "ivf":
             self.db = x
-            self.ivf = ivf_mod.build_ivf(x, cfg.ivf, cfg.quant)
+            with self._stage("ivf"):     # coarse k-means, PQ, list layout
+                self.ivf = ivf_mod.build_ivf(x, cfg.ivf, cfg.quant)
+                jax.block_until_ready(self.ivf.list_codes)
             return self
 
-        knn_ids, knn_dists = build_mod.build_knn(
-            x, b.knn_k, metric, builder=b.builder,
-            rounds=b.nn_descent_rounds, sample=b.nn_descent_sample, seed=b.seed)
+        with self._stage("knn"):
+            knn_ids, knn_dists = build_mod.build_knn(
+                x, b.knn_k, metric, builder=b.builder,
+                rounds=b.nn_descent_rounds, sample=b.nn_descent_sample,
+                seed=b.seed)
+            jax.block_until_ready(knn_ids)
 
-        entry = build_mod.medoid(x, metric)
-        graph = refine_graph(
-            x, knn_ids, knn_dists, M=b.M, rule=b.select_rule, metric=metric,
-            alpha=b.alpha, ssg_angle_deg=b.ssg_angle_deg,
-            iters=b.refine_iters, cand_cap=b.refine_cands,
-            entry=entry, search_L=b.search_L, search_passes=b.search_passes)
+        with self._stage("refine"):
+            entry = build_mod.medoid(x, metric)
+            graph = refine_graph(
+                x, knn_ids, knn_dists, M=b.M, rule=b.select_rule,
+                metric=metric, alpha=b.alpha, ssg_angle_deg=b.ssg_angle_deg,
+                iters=b.refine_iters, cand_cap=b.refine_cands, entry=entry,
+                search_L=b.search_L, search_passes=b.search_passes)
 
-        if b.reorder != "none":
-            weights = np.asarray(_edge_weights(x, graph, metric))
-            if b.reorder == "mst":
-                order = reorder_mod.mst_reorder(np.asarray(graph), weights, entry)
-            elif b.reorder == "cm":
-                order = reorder_mod.cuthill_mckee(np.asarray(graph), entry)
-            else:
-                raise ValueError(b.reorder)
-            db2, g2, new_of_old = reorder_mod.apply_order(
-                order, np.asarray(x), np.asarray(graph))
-            x, graph = jnp.asarray(db2), jnp.asarray(g2)
-            entry = int(new_of_old[entry])
-            self.order = order
+        with self._stage("reorder"):
+            if b.reorder != "none":
+                weights = np.asarray(_edge_weights(x, graph, metric))
+                if b.reorder == "mst":
+                    order = reorder_mod.mst_reorder(np.asarray(graph),
+                                                    weights, entry)
+                elif b.reorder == "cm":
+                    order = reorder_mod.cuthill_mckee(np.asarray(graph),
+                                                      entry)
+                else:
+                    raise ValueError(b.reorder)
+                db2, g2, new_of_old = reorder_mod.apply_order(
+                    order, np.asarray(x), np.asarray(graph))
+                x, graph = jnp.asarray(db2), jnp.asarray(g2)
+                entry = int(new_of_old[entry])
+                self.order = jnp.asarray(order, jnp.int32)
 
         self.db, self.graph, self.entry = x, jnp.asarray(graph), entry
-        self._train_quant(x)
+        with self._stage("quant"):
+            self._train_quant(x)
         return self
 
     def _train_quant(self, x: jnp.ndarray) -> None:
@@ -129,6 +154,7 @@ class KBest:
         elif q.kind == "bin":
             self.bin = qz.bin_train(x, q)
             self.bin_codes = qz.bin_encode(self.bin, x)   # (n, ceil(d/32)) u32
+        jax.block_until_ready((self.pq_codes, self.sq_codes, self.bin_codes))
 
     # --------------------------------------------------------------- search
     def search(self, queries: np.ndarray, k: Optional[int] = None,
@@ -182,7 +208,8 @@ class KBest:
                      valid_mask: Optional[jnp.ndarray],
                      with_stats: bool):
         """Shared body of search/search_padded. Pure jax ops on concrete
-        configs, so the serving engine can close over it under one jit trace
+        configs over the index's arrays, so the serving engine can jit it
+        with the index itself as an argument (KBest is a pytree), one trace
         per (shape bucket, config) key. Returns (dists, ids, stats|None)."""
         cfg = self.config
         metric = "ip" if cfg.metric == "cosine" else cfg.metric
@@ -229,19 +256,19 @@ class KBest:
                 tables = qz.pq4_query_tables(self.pq.codebooks, q, metric,
                                              lut_u8=cfg.quant.pq4_lut_u8)
             wide = _widen(scfg)
-            dist_fn = self._get_dist_fn(quant, scfg.dist_impl)
+            dist_fn, data = self._get_dist_fn(quant, scfg.dist_impl)
             dists, ids, stats = search_mod.search(
-                self.graph, tables, entry_ids, dist_fn=dist_fn, cfg=wide,
-                n_total=n, valid_mask=valid_mask,
+                self.graph, tables, entry_ids, data, dist_fn=dist_fn,
+                cfg=wide, n_total=n, valid_mask=valid_mask,
                 expand_fn=self._get_expand_fn(quant, wide))
             dists, ids, n_exact = self._rerank(q, ids, metric, scfg.k,
                                                cfg.quant.rerank,
                                                impl=scfg.dist_impl)
         elif quant == "sq":
             wide = _widen(scfg)
-            dist_fn = self._get_dist_fn("sq", scfg.dist_impl)
+            dist_fn, data = self._get_dist_fn("sq", scfg.dist_impl)
             dists, ids, stats = search_mod.search(
-                self.graph, q, entry_ids, dist_fn=dist_fn, cfg=wide,
+                self.graph, q, entry_ids, data, dist_fn=dist_fn, cfg=wide,
                 n_total=n, valid_mask=valid_mask,
                 expand_fn=self._get_expand_fn("sq", wide))
             dists, ids, n_exact = self._rerank(q, ids, metric, scfg.k,
@@ -253,10 +280,10 @@ class KBest:
             # candidates, then exact re-rank that overfetch
             qcodes = qz.bin_query_codes(self.bin, q)
             wide = _widen_bin(scfg)
-            dist_fn = self._get_dist_fn("bin", scfg.dist_impl)
+            dist_fn, data = self._get_dist_fn("bin", scfg.dist_impl)
             dists, ids, stats = search_mod.search(
-                self.graph, qcodes, entry_ids, dist_fn=dist_fn, cfg=wide,
-                n_total=n, valid_mask=valid_mask,
+                self.graph, qcodes, entry_ids, data, dist_fn=dist_fn,
+                cfg=wide, n_total=n, valid_mask=valid_mask,
                 expand_fn=self._get_expand_fn("bin", wide))
             rr = cfg.quant.rerank if cfg.quant.rerank > 0 \
                 else scfg.rescore_factor * scfg.k
@@ -264,9 +291,9 @@ class KBest:
                                                impl=scfg.dist_impl)
         else:
             n_exact = None
-            dist_fn = self._get_dist_fn("full", scfg.dist_impl)
+            dist_fn, data = self._get_dist_fn("full", scfg.dist_impl)
             dists, ids, stats = search_mod.search(
-                self.graph, q, entry_ids, dist_fn=dist_fn, cfg=scfg,
+                self.graph, q, entry_ids, data, dist_fn=dist_fn, cfg=scfg,
                 n_total=n, valid_mask=valid_mask,
                 expand_fn=self._get_expand_fn("full", scfg))
 
@@ -279,8 +306,7 @@ class KBest:
 
         # translate internal (post-reorder) ids back to the user's add() ids
         if self.order is not None:
-            order = jnp.asarray(self.order, dtype=jnp.int32)
-            ids = jnp.where(ids >= 0, order[jnp.maximum(ids, 0)], -1)
+            ids = jnp.where(ids >= 0, self.order[jnp.maximum(ids, 0)], -1)
 
         return dists, ids, (stats if with_stats else None)
 
@@ -303,70 +329,47 @@ class KBest:
         ids = (self.entry + np.concatenate([[0], off])) % n
         return jnp.asarray(ids, jnp.int32)
 
+    def _metric(self) -> str:
+        return "ip" if self.config.metric == "cosine" else self.config.metric
+
     def _get_dist_fn(self, kind: str, impl: str):
-        key = (kind, impl)
-        if key not in self._dist_fns:
-            metric = "ip" if self.config.metric == "cosine" else self.config.metric
-            if kind == "full":
-                fn = search_mod.make_dist_fn(self.db, metric, impl)
-            elif kind == "pq":
-                fn = qz.pq_make_dist_fn(self.pq_codes, self.pq.m, impl)
-            elif kind == "pq4":
-                fn = qz.pq4_make_dist_fn(self.pq_codes, self.pq.m, impl)
-            elif kind == "sq":
-                fn = qz.sq_make_dist_fn(self.sq_codes, self.sq, metric, impl)
-            elif kind == "bin":
-                fn = qz.bin_make_dist_fn(self.bin_codes, impl)
-            else:
-                raise ValueError(kind)
-            self._dist_fns[key] = fn
-        return self._dist_fns[key]
+        """(dist_fn, data) for the traversal: a cached pure function of the
+        static knobs, and the array operand it reads (the corpus or the
+        codes) — passed to core.search as an argument, never captured."""
+        if kind == "full":
+            return search_mod.make_dist_fn(self._metric(), impl), self.db
+        if kind == "pq":
+            return qz.pq_make_dist_fn(self.pq.m, impl), self.pq_codes
+        if kind == "pq4":
+            return qz.pq4_make_dist_fn(self.pq.m, impl), self.pq_codes
+        if kind == "sq":
+            return (qz.sq_make_dist_fn(self._metric(), impl),
+                    (self.sq_codes, self.sq))
+        if kind == "bin":
+            return qz.bin_make_dist_fn(impl), self.bin_codes
+        raise ValueError(kind)
 
     def _get_expand_fn(self, kind: str, scfg: SearchConfig):
-        """Fused gather+distance+sort backend for the beam traversal
-        (kernels/traverse_step.py), or None for the dist_fn + host-sort
+        """Fused gather+distance+sort backend for the beam traversal (the
+        kernels' fused_expand family), or None for the dist_fn + host-sort
         path. Engaged only for kernel-impl beam searches: W=1 keeps the
         seed gather-then-merge kernel path (the bit-parity anchor), and a
         set batch_B means chunked dist_fn calls (core.search honors the
-        knob by falling back). Cached per (kind, L, W) — the closures are
-        jit static args, so their identity must be stable across calls."""
+        knob by falling back). It reads the same data operand as
+        _get_dist_fn(kind, ...)."""
         if scfg.dist_impl != "kernel" or scfg.beam_width <= 1 \
                 or scfg.batch_B != 0:
             return None
         L, W = scfg.L, scfg.beam_width
-        key = (kind, "expand", L, W)
-        if key not in self._dist_fns:
-            from repro.kernels import ops as kops
-            metric = "ip" if self.config.metric == "cosine" else self.config.metric
-            if kind == "full":
-                fn = search_mod.make_expand_fn(self.db, metric, L=L, n_beam=W)
-            elif kind in ("pq", "pq4"):
-                m = self.pq.m
-                K = 16 if kind == "pq4" else 256
-                codes = self.pq_codes
-                fe = kops.fused_expand_pq4 if kind == "pq4" else kops.fused_expand_pq
-
-                def fn(tables, nbr_ids, _fe=fe, _m=m, _K=K, _codes=codes):
-                    lut = tables.reshape(tables.shape[0], _m, _K)
-                    return _fe(lut, _codes, nbr_ids, L=L, n_beam=W)
-            elif kind == "sq":
-                codes, sq = self.sq_codes, self.sq
-
-                def fn(queries, nbr_ids, _codes=codes, _sq=sq):
-                    return kops.fused_expand_sq(
-                        queries, _codes, _sq.scale.reshape(1, -1),
-                        _sq.zero.reshape(1, -1), nbr_ids,
-                        metric=metric, L=L, n_beam=W)
-            elif kind == "bin":
-                codes = self.bin_codes
-
-                def fn(qcodes, nbr_ids, _codes=codes):
-                    return kops.fused_expand_bin(qcodes, _codes, nbr_ids,
-                                                 L=L, n_beam=W)
-            else:
-                raise ValueError(kind)
-            self._dist_fns[key] = fn
-        return self._dist_fns[key]
+        if kind == "full":
+            return search_mod.make_expand_fn(self._metric(), L=L, n_beam=W)
+        if kind in ("pq", "pq4"):
+            return _pq_expand_fn(kind, self.pq.m, L, W)
+        if kind == "sq":
+            return _sq_expand_fn(self._metric(), L, W)
+        if kind == "bin":
+            return _bin_expand_fn(L, W)
+        raise ValueError(kind)
 
     def _rerank(self, q, ids, metric, k, rerank, impl: str = "ref"):
         """Exact re-rank of the quantized/IVF search's top candidates, via
@@ -483,9 +486,67 @@ class KBest:
             idx.bin = qz.BinState(jnp.asarray(z["bin_rot"]))
             idx.bin_codes = jnp.asarray(z["bin_codes"])
         if "order" in z:
-            idx.order = np.asarray(z["order"])
+            idx.order = jnp.asarray(z["order"], jnp.int32)
         idx.entry = int(meta["entry"])
         return idx
+
+
+# KBest is a pytree: its arrays (corpus, graph, id map, codes, quantizer and
+# IVF states) are the leaves, config and entry point static metadata. The
+# serving engine jits `index.search_padded` with the index as an ARGUMENT,
+# so the arrays are held once on the device and every compiled program
+# reads them as operands — never as embedded constants.
+_KBEST_LEAVES = ("db", "graph", "order", "pq", "pq_codes", "sq", "sq_codes",
+                 "bin", "bin_codes", "ivf")
+
+
+def _kbest_flatten(idx: KBest):
+    return (tuple(getattr(idx, a) for a in _KBEST_LEAVES),
+            (idx.config, idx.entry))
+
+
+def _kbest_unflatten(aux, leaves) -> KBest:
+    idx = KBest.__new__(KBest)
+    idx.config, idx.entry = aux
+    for a, v in zip(_KBEST_LEAVES, leaves):
+        setattr(idx, a, v)
+    return idx
+
+
+jax.tree_util.register_pytree_node(KBest, _kbest_flatten, _kbest_unflatten)
+
+
+@functools.lru_cache(maxsize=None)
+def _pq_expand_fn(kind: str, m: int, L: int, W: int):
+    from repro.kernels import ops as kops
+    K = 16 if kind == "pq4" else 256
+    fe = kops.fused_expand_pq4 if kind == "pq4" else kops.fused_expand_pq
+
+    def fn(codes, tables, nbr_ids):
+        lut = tables.reshape(tables.shape[0], m, K)
+        return fe(lut, codes, nbr_ids, L=L, n_beam=W)
+    return fn
+
+
+@functools.lru_cache(maxsize=None)
+def _sq_expand_fn(metric: str, L: int, W: int):
+    from repro.kernels import ops as kops
+
+    def fn(data, queries, nbr_ids):
+        codes, sq = data
+        return kops.fused_expand_sq(
+            queries, codes, sq.scale.reshape(1, -1), sq.zero.reshape(1, -1),
+            nbr_ids, metric=metric, L=L, n_beam=W)
+    return fn
+
+
+@functools.lru_cache(maxsize=None)
+def _bin_expand_fn(L: int, W: int):
+    from repro.kernels import ops as kops
+
+    def fn(codes, qcodes, nbr_ids):
+        return kops.fused_expand_bin(qcodes, codes, nbr_ids, L=L, n_beam=W)
+    return fn
 
 
 def resolve_search_cfg(config: IndexConfig, k: Optional[int],

@@ -70,6 +70,13 @@ class IVFState:
         return self.list_ids.shape[1]
 
 
+# a pytree, like the quantizer states: arrays are leaves (jit arguments of
+# the served program), the layout flags static metadata
+jax.tree_util.register_dataclass(
+    IVFState, data_fields=["centroids", "list_ids", "list_codes", "pq", "bin"],
+    meta_fields=["residual", "packed"])
+
+
 def auto_nlist(n: int) -> int:
     """sqrt(n) heuristic, clamped so tiny corpora still get >= 2 cells."""
     return max(2, min(n, int(round(float(np.sqrt(n))))))

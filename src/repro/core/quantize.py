@@ -7,7 +7,8 @@ can be swapped without touching the search core; we mirror that:
   Quantizer.encode(db)     -> codes
   Quantizer.query_tables(q)-> per-query operand passed to search() as the
                               "queries" array (the search loop is agnostic)
-  Quantizer.make_dist_fn() -> DistFn consuming (tables, nbr_ids)
+  Quantizer.make_dist_fn() -> DistFn consuming (codes, tables, nbr_ids) —
+                              the codes are an operand, never captured
 
 PQ distance is ADC (asymmetric distance computation): per query build an
 (m, K) lookup table of subspace distances; a database code (m,) then costs m
@@ -86,6 +87,13 @@ class PQState:
         return self.codebooks.shape[1]
 
 
+# The quantizer states are pytrees (arrays as leaves, sizes as static
+# metadata), so a whole index can be handed to a jitted program as an
+# argument (serve/engine.py) instead of being captured as constants.
+jax.tree_util.register_dataclass(PQState, data_fields=["codebooks"],
+                                 meta_fields=["m", "ds"])
+
+
 def pq_train(db: jnp.ndarray, cfg: QuantConfig) -> PQState:
     """Train per-subspace codebooks; K follows cfg.kind (256 or 16)."""
     n, d = db.shape
@@ -101,16 +109,27 @@ def pq_train(db: jnp.ndarray, cfg: QuantConfig) -> PQState:
     return PQState(codebooks=books, m=m, ds=ds)
 
 
-@functools.partial(jax.jit, static_argnames=())
+# Bytes of (rows, m, K) subspace distances one pq_encode chunk may hold:
+# a 1M-row corpus scored against (16, 256) centroids at once is 16 GB.
+ENCODE_BUDGET = 1 << 28
+
+
+@jax.jit
 def pq_encode(state_books: jnp.ndarray, db: jnp.ndarray) -> jnp.ndarray:
-    """(n, d) -> (n, m) uint8 codes."""
+    """(n, d) -> (n, m) uint8 codes, ENCODE_BUDGET bytes of distances at a
+    time."""
+    from repro.core.build import map_rows
     m, K, ds = state_books.shape
     n = db.shape[0]
-    subs = db.reshape(n, m, ds)
-    d2 = (jnp.sum(subs * subs, -1)[:, :, None]
-          + jnp.sum(state_books * state_books, -1)[None]
-          - 2.0 * jnp.einsum("nmd,mkd->nmk", subs, state_books))
-    return jnp.argmin(d2, axis=-1).astype(jnp.uint8)
+    bb = jnp.sum(state_books * state_books, -1)[None]
+
+    def encode_rows(rows):
+        subs = db[rows].reshape(rows.shape[0], m, ds)
+        d2 = (jnp.sum(subs * subs, -1)[:, :, None] + bb
+              - 2.0 * jnp.einsum("nmd,mkd->nmk", subs, state_books))
+        return jnp.argmin(d2, axis=-1).astype(jnp.uint8)
+
+    return map_rows(encode_rows, n, max(1, ENCODE_BUDGET // (4 * m * K)))
 
 
 @functools.partial(jax.jit, static_argnames=("metric",))
@@ -133,20 +152,22 @@ def pq_query_tables(state_books: jnp.ndarray, queries: jnp.ndarray, metric: str
     return lut.reshape(Q, m * K)
 
 
-def pq_make_dist_fn(codes: jnp.ndarray, m: int, impl: str = "ref"):
-    """DistFn over PQ codes. `tables` (the search "queries") is (Q, m*256)."""
+@functools.lru_cache(maxsize=None)
+def pq_make_dist_fn(m: int, impl: str = "ref"):
+    """DistFn over (n, m) PQ codes; `tables` (the search "queries") is
+    (Q, m*256)."""
     K = 256
 
     if impl == "kernel":
         from repro.kernels import ops as kops
 
-        def fn(tables, nbr_ids):
+        def fn(codes, tables, nbr_ids):
             return kops.pq_adc(tables.reshape(tables.shape[0], m, K),
                                codes, nbr_ids)
         return fn
 
-    def fn(tables, nbr_ids):
-        Q, MB = tables.shape[0], nbr_ids.shape[1]
+    def fn(codes, tables, nbr_ids):
+        Q = tables.shape[0]
         lut = tables.reshape(Q, m, K)
         c = codes[jnp.maximum(nbr_ids, 0)]          # (Q, B, m) uint8
         g = jnp.take_along_axis(
@@ -216,19 +237,21 @@ def pq4_query_tables(state_books: jnp.ndarray, queries: jnp.ndarray,
     return pq4_requant_lut(lut) if lut_u8 else lut
 
 
-def pq4_make_dist_fn(packed: jnp.ndarray, m: int, impl: str = "ref"):
-    """DistFn over nibble-packed PQ4 codes; `tables` is (Q, m*16)."""
+@functools.lru_cache(maxsize=None)
+def pq4_make_dist_fn(m: int, impl: str = "ref"):
+    """DistFn over (n, m//2) nibble-packed PQ4 codes; `tables` is
+    (Q, m*16)."""
     K = 16
 
     if impl == "kernel":
         from repro.kernels import ops as kops
 
-        def fn(tables, nbr_ids):
+        def fn(packed, tables, nbr_ids):
             return kops.pq4_adc(tables.reshape(tables.shape[0], m, K),
                                 packed, nbr_ids)
         return fn
 
-    def fn(tables, nbr_ids):
+    def fn(packed, tables, nbr_ids):
         Q = tables.shape[0]
         lut = tables.reshape(Q, m, K)
         c = pq4_unpack(packed[jnp.maximum(nbr_ids, 0)])   # (Q, B, m) i32
@@ -244,6 +267,10 @@ def pq4_make_dist_fn(packed: jnp.ndarray, m: int, impl: str = "ref"):
 class SQState:
     scale: jnp.ndarray   # (d,)
     zero: jnp.ndarray    # (d,)
+
+
+jax.tree_util.register_dataclass(SQState, data_fields=["scale", "zero"],
+                                 meta_fields=[])
 
 
 def sq_train(db: jnp.ndarray) -> SQState:
@@ -264,9 +291,10 @@ def _sq_encode(scale: jnp.ndarray, zero: jnp.ndarray, db: jnp.ndarray
     return jnp.clip(q, 0, 255).astype(jnp.uint8)
 
 
-def sq_make_dist_fn(codes: jnp.ndarray, state: SQState, metric: str,
-                    impl: str = "ref"):
-    """DistFn with on-the-fly dequantization.
+@functools.lru_cache(maxsize=None)
+def sq_make_dist_fn(metric: str, impl: str = "ref"):
+    """DistFn with on-the-fly dequantization; its operand is the pair
+    (codes (n, d) u8, SQState).
 
     impl="kernel" routes through the fused sq_gather_dist Pallas kernel
     (u8 rows gathered by scalar-prefetch, dequantized in-VMEM); impl="ref"
@@ -277,14 +305,16 @@ def sq_make_dist_fn(codes: jnp.ndarray, state: SQState, metric: str,
     if impl == "kernel":
         from repro.kernels import ops as kops
 
-        def fn(queries, nbr_ids):
+        def fn(data, queries, nbr_ids):
+            codes, state = data
             return kops.sq_gather_dist(queries, codes, state.scale,
                                        state.zero, nbr_ids, metric=metric)
         return fn
 
     from repro.core.distance import batched_one_to_many
 
-    def fn(queries, nbr_ids):
+    def fn(data, queries, nbr_ids):
+        codes, state = data
         c = codes[jnp.maximum(nbr_ids, 0)].astype(jnp.float32)
         vecs = c * state.scale[None, None, :] + state.zero[None, None, :]
         return batched_one_to_many(queries, vecs, metric)
@@ -305,6 +335,10 @@ class BinState:
     @property
     def n_words(self) -> int:
         return -(-self.dim // 32)
+
+
+jax.tree_util.register_dataclass(BinState, data_fields=["rot"],
+                                 meta_fields=[])
 
 
 def _random_rotation(d: int, seed: int) -> jnp.ndarray:
@@ -364,19 +398,21 @@ def bin_query_codes(state: BinState, queries: jnp.ndarray) -> jnp.ndarray:
     return _bin_encode(state.rot, queries)
 
 
-def bin_make_dist_fn(codes: jnp.ndarray, impl: str = "ref"):
-    """DistFn over packed bin codes; `qcodes` (the search "queries") is
-    (Q, nw) uint32. Distances are exact integer Hamming counts in f32."""
+@functools.lru_cache(maxsize=None)
+def bin_make_dist_fn(impl: str = "ref"):
+    """DistFn over (n, nw) packed bin codes; `qcodes` (the search
+    "queries") is (Q, nw) uint32. Distances are exact integer Hamming
+    counts in f32."""
     if impl == "kernel":
         from repro.kernels import ops as kops
 
-        def fn(qcodes, nbr_ids):
+        def fn(codes, qcodes, nbr_ids):
             return kops.bin_dist(qcodes, codes, nbr_ids)
         return fn
 
     from repro.kernels.ref import bin_dist_ref
 
-    def fn(qcodes, nbr_ids):
+    def fn(codes, qcodes, nbr_ids):
         return bin_dist_ref(qcodes, codes, nbr_ids)
     return fn
 

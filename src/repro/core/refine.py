@@ -23,6 +23,8 @@ search_passes); the per-dataset values live in `configs/kbest.py`.
 from __future__ import annotations
 
 import functools
+import logging
+import time
 from typing import Tuple
 
 import jax
@@ -30,6 +32,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.build import _merge_topk
+
+log = logging.getLogger(__name__)
+
+# Rows per device call in every chunked refinement step. Each row's result
+# is independent of its chunk, so this only trades memory for dispatches:
+# the build-time searches are launch-bound at small chunks (512-row chunks
+# spent 635 s in the search pass of a 1M-row build on one TPU v5e).
+NODE_CHUNK = 4096
 
 
 @functools.partial(jax.jit, static_argnames=("M", "rule", "metric"))
@@ -116,21 +126,23 @@ def _reverse_proposals(graph: np.ndarray, cap: int) -> np.ndarray:
     """(n, cap) int32 of reverse-edge proposers per node (-1 padded)."""
     n, M = graph.shape
     out = np.full((n, cap), -1, dtype=np.int32)
-    cnt = np.zeros(n, dtype=np.int64)
     us = np.repeat(np.arange(n, dtype=np.int32), M)
     vs = graph.reshape(-1)
     ok = vs >= 0
-    for u, v in zip(us[ok], vs[ok]):
-        c = cnt[v]
-        if c < cap:
-            out[v, c] = u
-            cnt[v] = c + 1
+    us, vs = us[ok], vs[ok]
+    # group the edges by target, keeping edge order within a group: the
+    # first `cap` proposers of v in (u, slot) order fill out[v]
+    order = np.argsort(vs, kind="stable")
+    us, vs = us[order], vs[order]
+    rank = np.arange(vs.size) - np.searchsorted(vs, vs, side="left")
+    keep = rank < cap
+    out[vs[keep], rank[keep]] = us[keep]
     return out
 
 
 def reverse_merge_select(db: jnp.ndarray, graph: np.ndarray, *, M: int,
                          rule: str, metric: str, alpha: float,
-                         cos_theta: float, node_chunk: int = 512,
+                         cos_theta: float, node_chunk: int = NODE_CHUNK,
                          rev_cap: int = None) -> np.ndarray:
     """Vamana-style reverse-edge pass WITH re-pruning.
 
@@ -169,21 +181,24 @@ def add_reverse_edges(graph: np.ndarray, max_degree: int) -> np.ndarray:
     Standard Vamana/NSG post-pass: every edge u->v proposes v->u; accepted
     while v has spare capacity. Keeps the graph closer to strongly-connected.
     """
-    graph = np.asarray(graph).copy()
+    graph = np.asarray(graph)
     n, M = graph.shape
     assert max_degree <= M
-    deg = (graph >= 0).sum(axis=1)
-    existing = [set(row[row >= 0].tolist()) for row in graph]
+    # plain Python lists: this loop is sequential by nature (a row may grow
+    # while later rows are scanned), and list indexing is several times
+    # faster than numpy scalar indexing at 1M rows
+    g = graph.tolist()
+    deg = (graph >= 0).sum(axis=1).tolist()
+    existing = [set(x for x in r if x >= 0) for r in g]
     for u in range(n):
-        for v in graph[u]:
+        for v in g[u]:
             if v < 0:
                 continue
-            v = int(v)
             if deg[v] < max_degree and u not in existing[v]:
-                graph[v, deg[v]] = u
+                g[v][deg[v]] = u
                 existing[v].add(u)
                 deg[v] += 1
-    return graph
+    return np.asarray(g, dtype=graph.dtype)
 
 
 def search_candidates(db: jnp.ndarray, graph: jnp.ndarray, rows: jnp.ndarray,
@@ -204,9 +219,9 @@ def search_candidates(db: jnp.ndarray, graph: jnp.ndarray, rows: jnp.ndarray,
 
     cfg = SearchConfig(L=search_L, k=search_L, early_term=False,
                        visited_mode="queue", n_entries=1)
-    dist_fn = search_mod.make_dist_fn(db, metric, "ref")
+    dist_fn = search_mod.make_dist_fn(metric, "ref")
     dists, ids, _ = search_mod.search(
-        graph, db[rows], jnp.array([entry], jnp.int32), dist_fn=dist_fn,
+        graph, db[rows], jnp.array([entry], jnp.int32), db, dist_fn=dist_fn,
         cfg=cfg, n_total=db.shape[0])
     ids = jnp.where(ids == rows[:, None], -1, ids)   # drop self
     dists = jnp.where(ids >= 0, dists, jnp.inf)
@@ -217,11 +232,20 @@ def refine_graph(db: jnp.ndarray, knn_ids: jnp.ndarray, knn_dists: jnp.ndarray,
                  *, M: int, rule: str, metric: str, alpha: float,
                  ssg_angle_deg: float, iters: int, cand_cap: int,
                  entry: int = 0, search_L: int = 48, search_passes: int = 1,
-                 node_chunk: int = 512) -> np.ndarray:
+                 node_chunk: int = NODE_CHUNK) -> np.ndarray:
     """Full A1 pipeline. Returns the final (n, M) int32 padded CSR graph."""
     cos_theta = float(np.cos(np.deg2rad(ssg_angle_deg)))
     n = db.shape[0]
     all_rows = jnp.arange(n, dtype=jnp.int32)
+    t0 = [time.perf_counter()]
+
+    def done(step, out):
+        """Log one sub-step's wall time (build set-up telemetry)."""
+        jax.block_until_ready(out)
+        t = time.perf_counter()
+        log.info("refine %s: %.1f s", step, t - t0[0])
+        t0[0] = t
+        return out
 
     def _select(cids, cdists):
         outs = []
@@ -235,7 +259,7 @@ def refine_graph(db: jnp.ndarray, knn_ids: jnp.ndarray, knn_dists: jnp.ndarray,
     if rule == "none":
         graph = knn_ids[:, :M]
     else:
-        graph = _select(knn_ids, knn_dists)
+        graph = done("select", _select(knn_ids, knn_dists))
 
     # --- phase 2 of A1: search-based neighborhood refinement ----------------
     # The graph searched during refinement is {current graph} ∪ {R random
@@ -268,12 +292,12 @@ def refine_graph(db: jnp.ndarray, knn_ids: jnp.ndarray, knn_dists: jnp.ndarray,
                 jnp.full((e - s, 1), jnp.inf, jnp.float32), cand_cap)
             cid_chunks.append(ci)
             cd_chunks.append(cd)
-        graph = _select(jnp.concatenate(cid_chunks, 0),
-                        jnp.concatenate(cd_chunks, 0))
+        graph = done("search pass", _select(jnp.concatenate(cid_chunks, 0),
+                                            jnp.concatenate(cd_chunks, 0)))
         # Vamana-style reverse pass with re-pruning: stitches islands.
-        graph = jnp.asarray(reverse_merge_select(
+        graph = done("reverse merge", jnp.asarray(reverse_merge_select(
             db, np.asarray(graph), M=M, rule=sel_rule, metric=metric,
-            alpha=alpha, cos_theta=cos_theta, node_chunk=node_chunk))
+            alpha=alpha, cos_theta=cos_theta, node_chunk=node_chunk)))
 
     # --- phase 3 of A1: iterative 2-hop expansion ---------------------------
     for _ in range(iters):
@@ -286,64 +310,71 @@ def refine_graph(db: jnp.ndarray, knn_ids: jnp.ndarray, knn_dists: jnp.ndarray,
             cd_chunks.append(cd)
         cids = jnp.concatenate(cid_chunks, axis=0)
         cdists = jnp.concatenate(cd_chunks, axis=0)
-        graph = _select(cids, cdists)
+        graph = done("2-hop", _select(cids, cdists))
 
-    graph = add_reverse_edges(np.asarray(graph), M)
-    return connectivity_repair(db, graph, entry, metric)
+    graph = done("reverse edges", add_reverse_edges(np.asarray(graph), M))
+    return done("connectivity", connectivity_repair(db, graph, entry, metric))
+
+
+def _flood(g: np.ndarray, starts: np.ndarray, seen: np.ndarray) -> np.ndarray:
+    """Mark everything reachable from `starts` in `seen` (in place) by a
+    level-synchronous BFS over the padded graph; returns `seen`."""
+    frontier = np.unique(np.asarray(starts, np.int64))
+    frontier = frontier[~seen[frontier]]
+    seen[frontier] = True
+    while frontier.size:
+        nb = g[frontier].ravel()
+        nb = np.unique(nb[nb >= 0])
+        frontier = nb[~seen[nb]]
+        seen[frontier] = True
+    return seen
+
+
+def _nearest_seen(db: jnp.ndarray, rows: np.ndarray, seen: np.ndarray,
+                  metric: str, block: int = 256
+                  ) -> Tuple[np.ndarray, np.ndarray]:
+    """For each of `rows`, its nearest row among `seen` under the metric:
+    (ids, dists). Device-side, `block` rows against the corpus at a time."""
+    from repro.core.distance import pairwise
+    seen_d = jnp.asarray(seen)
+    ids, dists = [], []
+    for s in range(0, len(rows), block):
+        d = pairwise(db[jnp.asarray(rows[s:s + block])], db, metric)
+        d = jnp.where(seen_d[None, :], d, jnp.inf)
+        ids.append(np.asarray(jnp.argmin(d, axis=1)))
+        dists.append(np.asarray(jnp.min(d, axis=1)))
+    return np.concatenate(ids), np.concatenate(dists)
 
 
 def connectivity_repair(db: jnp.ndarray, graph: np.ndarray, entry: int,
                         metric: str) -> np.ndarray:
     """NSG-style spanning pass: guarantee every node is reachable from the
-    entry by linking each unreachable region to its nearest reachable node
-    (replacing the victim's worst edge if it has no spare slot)."""
-    import collections
+    entry by linking each unreachable region from its nearest reachable
+    node (replacing that node's worst edge if it has no spare slot).
+
+    Every unreachable node's nearest reachable node is found in one
+    blocked device pass; the nodes are then linked in ascending order of
+    that distance, each link followed by a flood fill, so a node that a
+    previous link already made reachable costs nothing. The work is linear
+    in the corpus however many regions are cut off (the former one-region-
+    per-iteration loop rescanned every reachable x unreachable pair per
+    region, which at 1M rows did not finish in 25 minutes)."""
     g = np.asarray(graph).copy()
     n, M = g.shape
-    dbn = np.asarray(db)
-
-    def reachable_set():
-        seen = np.zeros(n, dtype=bool)
-        dq = collections.deque([entry])
-        seen[entry] = True
-        while dq:
-            u = dq.popleft()
-            for v in g[u]:
-                if v >= 0 and not seen[v]:
-                    seen[v] = True
-                    dq.append(int(v))
-        return seen
-
-    seen = reachable_set()
-    guard = 0
-    while not seen.all() and guard < n:
-        guard += 1
-        un = np.nonzero(~seen)[0]
-        re = np.nonzero(seen)[0]
-        # nearest (reachable, unreachable) pair under the metric, chunked
-        best = (np.inf, -1, -1)
-        for s in range(0, len(un), 512):
-            u_blk = un[s:s + 512]
-            if metric == "l2":
-                d = (((dbn[re] ** 2).sum(1)[:, None]
-                      + (dbn[u_blk] ** 2).sum(1)[None])
-                     - 2.0 * dbn[re] @ dbn[u_blk].T)
-            else:
-                d = -(dbn[re] @ dbn[u_blk].T)
-            ij = np.unravel_index(np.argmin(d), d.shape)
-            if d[ij] < best[0]:
-                best = (float(d[ij]), int(re[ij[0]]), int(u_blk[ij[1]]))
-        _, r, u = best
+    seen = _flood(g, np.array([entry]), np.zeros(n, dtype=bool))
+    un = np.nonzero(~seen)[0]
+    if un.size == 0:
+        return g
+    near, dist = _nearest_seen(db, un, seen, metric)
+    for i in np.argsort(dist, kind="stable"):
+        u = un[i]
+        if seen[u]:
+            continue
+        r = near[i]
         spare = np.nonzero(g[r] < 0)[0]
         slot = spare[0] if len(spare) else M - 1   # replace worst (last) edge
         g[r, slot] = u
-        # flood-fill from u through the existing graph
-        dq = collections.deque([u])
-        seen[u] = True
-        while dq:
-            w = dq.popleft()
-            for v in g[w]:
-                if v >= 0 and not seen[v]:
-                    seen[v] = True
-                    dq.append(int(v))
+        _flood(g, np.array([u]), seen)
+    log.info("connectivity repair: %d of %d nodes were unreachable", un.size,
+             n)
     return g

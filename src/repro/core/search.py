@@ -16,22 +16,22 @@ off and become idle lanes (measured as `lockstep_overhead` in the
 benchmarks).
 
 Queue maintenance per iteration is a sort of the W·M-entry candidate block
-(small) plus a stable merge of two sorted runs (`queue.merge_insert_beam`) —
-never a full argsort of the (L + W·M) concatenation. Early termination under
-a beam is defined per expansion in beam order: expansion w's best surviving
-candidate gets its insertion rank in the merged order, and Eq. 3's patience
-counter consumes the W ranks sequentially (w = 0 first), exactly as if the
-expansions had happened one per iteration — so W=1 semantics are the
-original ones by construction, and a beam can only reach the patience
-threshold at the same expansion count or earlier.
+(small) plus a stable merge of two sorted runs (`queue.merge_sorted_runs`,
+one stable sort of the L + W·M concatenation: no scatters). Early
+termination under a beam is defined per expansion in beam order: expansion
+w's best surviving candidate gets its insertion rank in the merged order,
+and Eq. 3's patience counter consumes the W ranks sequentially (w = 0
+first), exactly as if the expansions had happened one per iteration — so
+W=1 semantics are the original ones by construction, and a beam can only
+reach the patience threshold at the same expansion count or earlier.
 
 The per-step distance batch: the paper sizes B to the L1 cache (Eq. 1). On
 TPU the analogous constraint is VMEM tile sizing inside the Pallas kernels
 (repro/kernels); `SearchConfig.batch_B` optionally chunks the W·M candidate
 axis into batch_B-sized distance calls (0 = one fused call). The
-full-precision kernel path under a beam routes through `fused_expand`
-(kernels/traverse_step.py): gather + distance + in-kernel sort emitting the
-sorted candidate block directly.
+kernel path under a beam routes through the `fused_expand` family
+(kernels/gather_dist.py and twins): the gather + distance kernel followed
+by the block sort, emitting the sorted candidate block directly.
 
 Visited-set semantics: "bitmap" mode implements Algorithm 1 exactly (a
 packed per-query bitmap of distance-computed nodes, O(n/8) bytes/query);
@@ -50,15 +50,18 @@ import jax.numpy as jnp
 from repro.core import queue as qmod
 from repro.core.types import SearchConfig
 
-# dist_fn(queries (Q, d), nbr_ids (Q, B)) -> (Q, B) float32 distances.
-DistFn = Callable[[jnp.ndarray, jnp.ndarray], jnp.ndarray]
+# dist_fn(data, queries (Q, d), nbr_ids (Q, B)) -> (Q, B) float32 distances.
+# `data` is the index's array operand (the corpus, or the codes of a
+# quantized family) — a traced argument of `search`, never a closed-over
+# constant: dist_fn itself is a pure, cached function of static knobs only.
+DistFn = Callable[[object, jnp.ndarray, jnp.ndarray], jnp.ndarray]
 
-# expand_fn(queries (Q, d), nbr_ids (Q, W*M)) ->
+# expand_fn(data, queries (Q, d), nbr_ids (Q, W*M)) ->
 #   (sorted_dists (Q, T), sorted_ids (Q, T), per-beam bests (Q, W),
 #    earlier-expansion tie counts (Q, W))
-# — the fused gather+distance+sort step (kernels/traverse_step.py). T is
-# min(L, W*M): only the L best new candidates can survive the merge.
-ExpandFn = Callable[[jnp.ndarray, jnp.ndarray],
+# — the fused gather+distance+sort step (the kernels' fused_expand family).
+# T is min(L, W*M): only the L best new candidates can survive the merge.
+ExpandFn = Callable[[object, jnp.ndarray, jnp.ndarray],
                     Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray,
                           jnp.ndarray]]
 
@@ -122,8 +125,8 @@ def _bitmap_set(bitmap: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
     return _bitmap_set_raw(bitmap, ids)
 
 
-def _dist_chunked(dist_fn: DistFn, queries: jnp.ndarray, ids: jnp.ndarray,
-                  batch_B: int) -> jnp.ndarray:
+def _dist_chunked(dist_fn: DistFn, data, queries: jnp.ndarray,
+                  ids: jnp.ndarray, batch_B: int) -> jnp.ndarray:
     """Distance computation over the candidate axis, optionally chunked into
     batch_B-sized calls (SearchConfig.batch_B; 0 = one fused (Q, W·M) call).
     Chunking never changes the candidate set or ordering (pinned by tests);
@@ -131,9 +134,9 @@ def _dist_chunked(dist_fn: DistFn, queries: jnp.ndarray, ids: jnp.ndarray,
     hardware retiling would."""
     C = ids.shape[1]
     if batch_B <= 0 or batch_B >= C:
-        return dist_fn(queries, ids)
+        return dist_fn(data, queries, ids)
     return jnp.concatenate(
-        [dist_fn(queries, ids[:, s:s + batch_B])
+        [dist_fn(data, queries, ids[:, s:s + batch_B])
          for s in range(0, C, batch_B)], axis=1)
 
 
@@ -145,6 +148,7 @@ def search(
     graph: jnp.ndarray,            # (n, M) i32, -1 padded
     queries: jnp.ndarray,          # (Q, d) f32
     entry_ids: jnp.ndarray,        # (E,) i32 entry points
+    data,                          # dist_fn / expand_fn array operand
     *,
     dist_fn: DistFn,
     cfg: SearchConfig,
@@ -159,6 +163,9 @@ def search(
     early-terminated queries and add no distance computations. Their rows
     still hold the (garbage) seed entries — callers mask outputs (the
     serving engine's `search_padded` does).
+
+    `data` (the corpus or codes) is a traced argument, so a compiled
+    search takes the index as an operand instead of embedding it.
 
     `expand_fn`, when given, replaces dist_fn + host-side block sort with
     the fused gather+distance+sort kernel for the per-iteration candidate
@@ -175,7 +182,7 @@ def search(
 
     # ---- init: seed the queue with the entry points -----------------------
     e_ids = jnp.broadcast_to(entry_ids[None, :], (Q, entry_ids.shape[0]))
-    e_dists = dist_fn(queries, e_ids)
+    e_dists = dist_fn(data, queries, e_ids)
     q0 = qmod.init_queue(L)
     q0 = jax.tree.map(lambda x: jnp.broadcast_to(x[None], (Q,) + x.shape), q0)
 
@@ -247,11 +254,12 @@ def search(
 
         # --- the 1-to-B (here Q-to-W·B) batched distance step (H1 + H2) ---
         if use_fused:
-            sd, si, bests, ties = expand_fn(queries, flat)
+            sd, si, bests, ties = expand_fn(data, queries, flat)
             merged = jax.vmap(qmod.merge_sorted_runs)(queue, sd, si)
             ranks = jax.vmap(qmod.block_ranks)(queue, sd, bests, ties)
         else:
-            nd = _dist_chunked(dist_fn, queries, flat, cfg.batch_B)
+            nd = _dist_chunked(dist_fn, data, queries, flat,
+                               cfg.batch_B)
             nd = jnp.where(flat >= 0, nd, jnp.inf)
             # flat is fully deduped/in-queue-masked above, so the merge can
             # skip _dedupe_new's O((WM)² + WM·L) re-derivation per step
@@ -286,38 +294,40 @@ def search(
     return dists_k, ids_k, stats
 
 
-def make_dist_fn(db: jnp.ndarray, metric: str, impl: str = "ref") -> DistFn:
-    """Gather-then-distance backend over a database (n, d).
+@functools.lru_cache(maxsize=None)
+def make_dist_fn(metric: str, impl: str = "ref") -> DistFn:
+    """Gather-then-distance backend over a database operand (n, d).
 
     impl="ref" is the jnp oracle; impl="kernel" routes through the Pallas
-    gather_dist kernel (interpret-mode on CPU).
+    gather_dist kernel (interpret-mode on CPU). Cached, so equal knobs give
+    the identical function (it is a static argument of `search`).
     """
     if impl == "kernel":
         from repro.kernels import ops as kops
 
-        def fn(queries, nbr_ids):
+        def fn(db, queries, nbr_ids):
             return kops.gather_dist(queries, db, nbr_ids, metric=metric)
         return fn
 
     from repro.core.distance import batched_one_to_many
 
-    def fn(queries, nbr_ids):
+    def fn(db, queries, nbr_ids):
         vecs = db[jnp.maximum(nbr_ids, 0)]
         return batched_one_to_many(queries, vecs, metric)
     return fn
 
 
-def make_expand_fn(db: jnp.ndarray, metric: str, *, L: int,
-                   n_beam: int) -> ExpandFn:
+@functools.lru_cache(maxsize=None)
+def make_expand_fn(metric: str, *, L: int, n_beam: int) -> ExpandFn:
     """Fused gather+distance+sort backend for the beam traversal's per-
-    iteration candidate block (full-precision rows): one Pallas kernel
+    iteration candidate block (full-precision rows): the Pallas kernel
     gathers the W·M neighbor rows with scalar-prefetch double-buffered DMA
-    (H2), computes distances on-chip (H1), and emits the per-query sorted
-    top-min(L, W·M) block plus per-expansion bests (the Eq. 3 operand) —
-    see kernels/traverse_step.py."""
+    (H2) and computes distances on-chip (H1); the per-query sorted
+    top-min(L, W·M) block plus per-expansion bests (the Eq. 3 operand)
+    follow in XLA — see kernels/gather_dist.py."""
     from repro.kernels import ops as kops
 
-    def fn(queries, nbr_ids):
+    def fn(db, queries, nbr_ids):
         return kops.fused_expand(queries, db, nbr_ids, metric=metric,
                                  L=L, n_beam=n_beam)
     return fn

@@ -65,7 +65,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import index as index_mod
 from repro.core import persist
 from repro.core import search as search_mod
@@ -282,6 +281,24 @@ class ShardedKBest:
         return idx
 
 
+# A pytree like KBest (core/index.py): the shards' arrays are the leaves,
+# so the serving engine hands the whole mesh to one jitted program as an
+# argument; config and row offsets are static metadata.
+jax.tree_util.register_pytree_node(
+    ShardedKBest,
+    lambda idx: (tuple(idx.shards),
+                 (idx.config, tuple(int(o) for o in idx.offsets))),
+    lambda aux, shards: _sharded_unflatten(aux, shards))
+
+
+def _sharded_unflatten(aux, shards) -> ShardedKBest:
+    idx = ShardedKBest.__new__(ShardedKBest)
+    idx.config = aux[0]
+    idx.offsets = np.asarray(aux[1], np.int64)
+    idx.shards = list(shards)
+    return idx
+
+
 # --------------------------------------------------------------------------
 # Device-mesh lowering of the sharded full-precision graph path (absorbed
 # from the old core/distributed.py). ShardedKBest above is the subsystem —
@@ -314,9 +331,9 @@ def build_sharded_search(mesh: Mesh, cfg: SearchConfig, metric: str,
     p_tot = mesh_size(mesh)
 
     def local_search(db_l, graph_l, entry_l, queries):
-        dist_fn = search_mod.make_dist_fn(db_l, metric, cfg.dist_impl)
+        dist_fn = search_mod.make_dist_fn(metric, cfg.dist_impl)
         dists, ids, _ = search_mod.search(
-            graph_l, queries, entry_l, dist_fn=dist_fn, cfg=cfg,
+            graph_l, queries, entry_l, db_l, dist_fn=dist_fn, cfg=cfg,
             n_total=n_local)
         # translate local -> global ids using this device's linear index
         idx = jnp.int32(0)
@@ -332,7 +349,7 @@ def build_sharded_search(mesh: Mesh, cfg: SearchConfig, metric: str,
         neg, pos = jax.lax.top_k(-all_d, k)
         return -neg, jnp.take_along_axis(all_i, pos, axis=1)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_search, mesh=mesh,
         in_specs=(row_spec, row_spec, row_spec, rep),
         out_specs=(rep, rep),

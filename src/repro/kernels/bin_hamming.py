@@ -9,21 +9,21 @@ their jnp oracles (ref.py) and parity tests assert ==, not allclose.
 
 Three kernels mirror the pq4 family one-for-one:
 
-  bin_dist        — graph-path gather Hamming, grid (Q, B): the packed
-                    code row of neighbor ids[q, b] streams by scalar
-                    prefetch (H2) against the query's VMEM-resident
-                    packed code — the per-row DMA is nw u32 words (16
-                    bytes at d=128), the smallest gather in the system.
-  fused_expand_bin — fused traversal step, grid (Q, C): Hamming into the
-                    VMEM scratch row per candidate, then the shared
-                    sorted-block epilogue (traverse_step._finalize) on
-                    the last step — identical queue contract to
-                    fused_expand_pq4.
-  bin_ivf_scan    — IVF list scan + per-list partial top-L, grid (Q, P).
+  bin_dist         — graph-path gather Hamming, grid (Q, C): the packed
+                     code row of neighbor ids[q, b] streams by scalar
+                     prefetch (H2) against the query's VMEM-resident
+                     packed code — the per-row DMA is nw u32 words (16
+                     bytes at d=128), the smallest gather in the system.
+  fused_expand_bin — the same gather, then the traversal's sorted-block
+                     epilogue in XLA — identical queue contract to
+                     fused_expand_pq4.
+  bin_ivf_scan     — IVF list scan, grid (Q, P, max_len // TL), per-list
+                     top-L in XLA after the kernel.
 
 Popcount is the SWAR bit-ladder (no LUT, no popcount intrinsic needed):
 pairs, nibbles, bytes, then a *0x01010101 horizontal byte-sum — pure
-shift/mask/add uint32 VPU ops, exact by construction.
+shift/mask/add uint32 VPU ops, exact by construction. Counts leave the
+u32 domain through i32 (Mosaic has no u32 -> f32 cast).
 """
 from __future__ import annotations
 
@@ -34,7 +34,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.traverse_step import _finalize, _out_shapes, _out_specs
+from repro.kernels.gather_dist import put_lane
+from repro.kernels.ivf_scan import list_tile, list_topk
+from repro.kernels.ref import sorted_block_ref
 
 
 def _popcount(x: jnp.ndarray) -> jnp.ndarray:
@@ -47,52 +49,37 @@ def _popcount(x: jnp.ndarray) -> jnp.ndarray:
 
 # ------------------------------------------------------------ graph gather
 def _dist_kernel(idx_ref, q_ref, code_ref, o_ref):
-    x = jnp.bitwise_xor(q_ref[...], code_ref[...])     # (1, nw) u32
-    o_ref[...] = jnp.sum(_popcount(x)).astype(jnp.float32).reshape(1, 1)
+    x = jnp.bitwise_xor(q_ref[0], code_ref[0])         # (1, nw) u32
+    cnt = jnp.sum(_popcount(x).astype(jnp.int32), axis=1, keepdims=True)
+    put_lane(o_ref, pl.program_id(1), cnt.astype(jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def bin_dist(qcodes: jnp.ndarray, codes: jnp.ndarray, ids: jnp.ndarray, *,
              interpret: bool = False) -> jnp.ndarray:
-    """(Q, nw) u32 packed queries, (n, nw) u32 packed codes, (Q, B) ids ->
-    (Q, B) f32 exact Hamming distances; invalid ids -> +inf."""
+    """(Q, nw) u32 packed queries, (n, nw) u32 packed codes, (Q, C) ids ->
+    (Q, C) f32 exact Hamming distances; invalid ids -> +inf."""
     Q, nw = qcodes.shape
     assert codes.shape[1] == nw, (codes.shape, nw)
-    B = ids.shape[1]
+    C = ids.shape[1]
     assert ids.shape[0] == Q
-    safe_ids = jnp.maximum(ids, 0)
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(Q, B),
+        grid=(Q, C),
         in_specs=[
-            pl.BlockSpec((1, nw), lambda i, j, idx_ref: (i, 0)),
-            pl.BlockSpec((1, nw), lambda i, j, idx_ref: (idx_ref[i, j], 0)),
+            pl.BlockSpec((1, 1, nw), lambda i, j, idx: (i, 0, 0)),
+            pl.BlockSpec((1, 1, nw),
+                         lambda i, j, idx: (jnp.maximum(idx[i, j], 0), 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j, idx_ref: (i, j)),
+        out_specs=pl.BlockSpec((1, 1, C), lambda i, j, idx: (i, 0, 0)),
     )
     out = pl.pallas_call(
         _dist_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Q, B), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((Q, 1, C), jnp.float32),
         interpret=interpret,
-    )(safe_ids, qcodes, codes)
-    return jnp.where(ids >= 0, out, jnp.inf)
-
-
-# ------------------------------------------------------- fused beam expand
-def _make_expand_kernel(C: int, T: int, W: int):
-    def kernel(idx_ref, q_ref, code_ref, od_ref, oi_ref, ob_ref,
-               ot_ref, acc_ref):
-        i, j = pl.program_id(0), pl.program_id(1)
-        x = jnp.bitwise_xor(q_ref[...], code_ref[...])   # (1, nw) u32
-        acc_ref[0, j] = jnp.sum(_popcount(x)).astype(jnp.float32)
-
-        @pl.when(j == C - 1)
-        def _():
-            _finalize(i, idx_ref, acc_ref, od_ref, oi_ref, ob_ref, ot_ref,
-                      T=T, W=W)
-    return kernel
+    )(ids, qcodes[:, None, :], codes[:, None, :])
+    return jnp.where(ids >= 0, out[:, 0, :], jnp.inf)
 
 
 @functools.partial(jax.jit, static_argnames=("L", "n_beam", "interpret"))
@@ -100,49 +87,37 @@ def fused_expand_bin(qcodes: jnp.ndarray, codes: jnp.ndarray,
                      ids: jnp.ndarray, *, L: int, n_beam: int = 1,
                      interpret: bool = False):
     """bin twin of fused_expand_pq4: (Q, nw) u32 packed queries, (n, nw)
-    u32 packed codes, (Q, C) ids -> sorted candidate block (dists (Q, T)
-    ascending, ids (Q, T), bests (Q, n_beam), tie counts (Q, n_beam));
-    T = min(L, C). ids < 0 are clamped for the DMA and come back (+inf, -1)."""
+    u32 packed codes, (Q, C) ids -> the sorted candidate block (dists
+    (Q, T) ascending, ids (Q, T), bests (Q, n_beam), tie counts
+    (Q, n_beam)); T = min(L, C). ids < 0 come back (+inf, -1)."""
     Q, nw = qcodes.shape
     C = ids.shape[1]
     assert codes.shape[1] == nw, (codes.shape, nw)
     assert ids.shape[0] == Q and C % n_beam == 0, (ids.shape, n_beam)
-    T = min(L, C)
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(Q, C),
         in_specs=[
-            pl.BlockSpec((1, nw), lambda i, j, idx_ref: (i, 0)),
-            # raw ids in prefetch (epilogue masks on sign); DMA clamp in
-            # the index map — same discipline as traverse_step
-            pl.BlockSpec((1, nw),
-                         lambda i, j, idx_ref: (jnp.maximum(idx_ref[i, j], 0), 0)),
+            pl.BlockSpec((1, 1, nw), lambda i, j, idx: (i, 0, 0)),
+            pl.BlockSpec((1, 1, nw),
+                         lambda i, j, idx: (jnp.maximum(idx[i, j], 0), 0, 0)),
         ],
-        out_specs=_out_specs(T, n_beam),
-        scratch_shapes=[pltpu.VMEM((1, C), jnp.float32)],
+        out_specs=pl.BlockSpec((1, 1, C), lambda i, j, idx: (i, 0, 0)),
     )
-    return pl.pallas_call(
-        _make_expand_kernel(C, T, n_beam),
+    dist = pl.pallas_call(
+        _dist_kernel,
         grid_spec=grid_spec,
-        out_shape=_out_shapes(Q, T, n_beam),
+        out_shape=jax.ShapeDtypeStruct((Q, 1, C), jnp.float32),
         interpret=interpret,
-    )(ids, qcodes, codes)
+    )(ids, qcodes[:, None, :], codes[:, None, :])
+    return sorted_block_ref(dist[:, 0, :], ids, L, n_beam)
 
 
 # ---------------------------------------------------------------- IVF scan
-def _make_scan_kernel(L: int):
-    def _kernel(pids_ref, q_ref, codes_ref, ids_ref, od_ref, oi_ref):
-        q = q_ref[0]                                     # (nw,) u32
-        codes = codes_ref[0]                             # (max_len, nw) u32
-        ids = ids_ref[0]                                 # (max_len,)
-        x = jnp.bitwise_xor(codes, q[None, :])
-        d = jnp.sum(_popcount(x), axis=-1).astype(jnp.float32)
-        d = jnp.where(ids >= 0, d, jnp.inf)
-        neg, pos = jax.lax.top_k(-d, L)
-        od_ref[0, 0] = -neg
-        oi_ref[0, 0] = jnp.where(jnp.isfinite(neg), ids[pos], -1)
-    return _kernel
+def _scan_kernel(pids_ref, q_ref, codes_ref, o_ref):
+    x = jnp.bitwise_xor(codes_ref[0], q_ref[0])         # (TL, nw) u32
+    cnt = _popcount(x).astype(jnp.int32).T              # (nw, TL)
+    o_ref[0, 0] = jnp.sum(cnt, axis=0, keepdims=True).astype(jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("L", "interpret"))
@@ -163,24 +138,22 @@ def bin_ivf_scan(qcodes: jnp.ndarray, list_codes: jnp.ndarray,
     assert nw2 == nw, (nw2, nw)
     assert list_ids.shape == (nlist, max_len)
     assert L <= max_len, (L, max_len)
+    TL = list_tile(max_len)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(Q, P),
+        grid=(Q, P, max_len // TL),
         in_specs=[
-            pl.BlockSpec((1, nw), lambda i, j, pids: (i, 0)),
-            pl.BlockSpec((1, max_len, nw), lambda i, j, pids: (pids[i, j], 0, 0)),
-            pl.BlockSpec((1, max_len), lambda i, j, pids: (pids[i, j], 0)),
+            pl.BlockSpec((1, 1, nw), lambda i, j, t, pids: (i, 0, 0)),
+            pl.BlockSpec((1, TL, nw), lambda i, j, t, pids: (pids[i, j], t, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, L), lambda i, j, pids: (i, j, 0)),
-            pl.BlockSpec((1, 1, L), lambda i, j, pids: (i, j, 0)),
-        ],
+        out_specs=pl.BlockSpec((1, 1, 1, TL),
+                               lambda i, j, t, pids: (i, j, 0, t)),
     )
-    return pl.pallas_call(
-        _make_scan_kernel(L),
+    d = pl.pallas_call(
+        _scan_kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((Q, P, L), jnp.float32),
-                   jax.ShapeDtypeStruct((Q, P, L), jnp.int32)],
+        out_shape=jax.ShapeDtypeStruct((Q, P, 1, max_len), jnp.float32),
         interpret=interpret,
-    )(probe_ids, qcodes, list_codes, list_ids)
+    )(probe_ids, qcodes[:, None, :], list_codes)
+    return list_topk(d[:, :, 0, :], list_ids, probe_ids, L)

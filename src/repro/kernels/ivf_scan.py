@@ -1,29 +1,24 @@
-"""ivf_scan — fused IVF-PQ list scan + partial top-L kernel (DESIGN.md §4).
+"""ivf_scan — IVF-PQ padded-list scan kernel (DESIGN.md §4).
 
-One grid step scans one (query, probed-list) pair: the ADC distances of every
-code in the list are computed against that pair's (m, K) lookup table, and
-the list is reduced to its top-L candidates *before* leaving the kernel, so
-output traffic per step is O(L) instead of O(max_len) — the partial
-reduction ScaNN-style CPU scanners do per SIMD register block, lifted to a
-whole inverted list per step.
+One grid step scans one tile of TL codes of one (query, probed-list) pair
+and writes their ADC distances as a lane-dense (1, TL) row; the per-list
+top-L then runs in XLA right after the kernel (`list_topk`: Mosaic has no
+in-kernel sort or top_k).
 
-The ADC gather itself is the same MXU idiom as pq_adc, batched over the
-list: one-hot expand the (max_len, m) code block and contract it against the
-flattened LUT — a (max_len, m*K) x (m*K, 1) matmul, i.e. the paper's 1-to-B
-H1 batching in its 2-D lift (same move as batch_dist, with the list playing
-the role of the neighbor batch).
+The ADC gather is a one-hot select on the VPU, with the list rows on the
+lanes: the (TL, m) code tile is transposed in-kernel to (m, TL), and for
+each subspace j the code row broadcasts down the K sublanes of an iota,
+selecting one entry of LUT column j per lane; the K-sublane sum adds it.
+The LUT is held transposed, (K, m), so column j is a lane slice.
 
-Prefetch (H2 analogue): the codes/ids blocks for step (q, p) are the rows of
-`list_codes`/`list_ids` selected by the scalar-prefetched `probe_ids[q, p]`,
-so the pipeline engine DMAs list p+1 while list p is being scanned — the
-software-prefetch trick of the paper's Fig. 5 applied to inverted lists.
+Prefetch (H2 analogue): the codes block of step (q, p, t) is tile t of the
+list `probe_ids[q, p]` (scalar-prefetched), so the pipeline engine DMAs the
+next tile while this one is scanned — the software-prefetch trick of the
+paper's Fig. 5 applied to inverted lists.
 
-Grid: (Q, P). Blocks: LUT (1, 1, m, K) by (q, p); codes (1, max_len, m) and
-ids (1, max_len) by probe_ids[q, p]; outputs (1, 1, L) by (q, p).
-
-NOTE: the in-kernel reduction uses jax.lax.top_k, which interpret mode (the
-CPU validation path, see ops.py) executes directly; on real TPU hardware
-Mosaic lowers it via a bitonic sort — keep L a power of two there.
+Grid: (Q, P, max_len // TL). Blocks: LUT (1, 1, K, m) by (q, p) (or by q
+when the table is probe-independent); codes (1, TL, m) by
+(probe_ids[q, p], t); output (1, 1, 1, TL) by (q, p, t).
 """
 from __future__ import annotations
 
@@ -35,27 +30,34 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _make_kernel(L: int):
-    def _kernel(pids_ref, lut_ref, codes_ref, ids_ref, od_ref, oi_ref):
-        lut = lut_ref[0, 0].astype(jnp.float32)          # (m, K)
-        codes = codes_ref[0].astype(jnp.int32)           # (max_len, m)
-        ids = ids_ref[0]                                 # (max_len,)
-        max_len, m = codes.shape
-        K = lut.shape[1]
-        # gather-as-matmul: onehot (max_len, m*K) @ lut (m*K, 1) on the MXU
-        onehot = (codes[:, :, None]
-                  == jax.lax.broadcasted_iota(jnp.int32, (max_len, m, K), 2)
-                  ).astype(jnp.float32)
-        d = jax.lax.dot_general(
-            onehot.reshape(max_len, m * K), lut.reshape(m * K, 1),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)[:, 0]    # (max_len,)
-        d = jnp.where(ids >= 0, d, jnp.inf)
-        # partial reduction: this list's top-L leaves the kernel, not max_len
-        neg, pos = jax.lax.top_k(-d, L)
-        od_ref[0, 0] = -neg
-        oi_ref[0, 0] = jnp.where(jnp.isfinite(neg), ids[pos], -1)
-    return _kernel
+def list_tile(max_len: int) -> int:
+    """Rows per scan step: the largest of 512/256/128 dividing max_len
+    (lane-aligned output tiles), else the whole (short, unaligned) list."""
+    return next((t for t in (512, 256, 128) if max_len % t == 0), max_len)
+
+
+def list_topk(d: jnp.ndarray, list_ids: jnp.ndarray, probe_ids: jnp.ndarray,
+              L: int):
+    """(Q, P, max_len) scan distances -> per-list top-L (dists ascending,
+    ids, -1 padding): list padding (-1 ids) is masked to +inf first."""
+    ids = list_ids[probe_ids]                            # (Q, P, max_len)
+    d = jnp.where(ids >= 0, d, jnp.inf)
+    neg, pos = jax.lax.top_k(-d, L)
+    out_ids = jnp.take_along_axis(ids, pos, axis=-1)
+    return -neg, jnp.where(jnp.isfinite(neg), out_ids, -1)
+
+
+def _kernel(pids_ref, lut_ref, codes_ref, o_ref):
+    lut = lut_ref[0, 0].astype(jnp.float32)              # (K, m)
+    codes = codes_ref[0].astype(jnp.int32).T             # (m, TL)
+    K, m = lut.shape
+    rows = jax.lax.broadcasted_iota(jnp.int32, (K, codes.shape[1]), 0)
+    acc = jnp.zeros((1, codes.shape[1]), jnp.float32)
+    for j in range(m):
+        hit = rows == codes[j:j + 1, :]
+        acc = acc + jnp.sum(jnp.where(hit, lut[:, j:j + 1], 0.0), axis=0,
+                            keepdims=True)
+    o_ref[0, 0] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("L", "interpret"))
@@ -79,26 +81,24 @@ def ivf_scan(luts: jnp.ndarray, list_codes: jnp.ndarray,
     assert Pl in (1, P), (Pl, P)
     assert probe_ids.shape == (Q, P) and list_codes.shape == (nlist, max_len, m)
     assert L <= max_len, (L, max_len)
-    lut_j = (lambda i, j, pids: (i, j, 0, 0)) if Pl == P else \
-        (lambda i, j, pids: (i, 0, 0, 0))
+    TL = list_tile(max_len)
+    lut_j = (lambda i, j, t, pids: (i, j, 0, 0)) if Pl == P else \
+        (lambda i, j, t, pids: (i, 0, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(Q, P),
+        grid=(Q, P, max_len // TL),
         in_specs=[
-            pl.BlockSpec((1, 1, m, K), lut_j),
-            pl.BlockSpec((1, max_len, m), lambda i, j, pids: (pids[i, j], 0, 0)),
-            pl.BlockSpec((1, max_len), lambda i, j, pids: (pids[i, j], 0)),
+            pl.BlockSpec((1, 1, K, m), lut_j),
+            pl.BlockSpec((1, TL, m), lambda i, j, t, pids: (pids[i, j], t, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, L), lambda i, j, pids: (i, j, 0)),
-            pl.BlockSpec((1, 1, L), lambda i, j, pids: (i, j, 0)),
-        ],
+        out_specs=pl.BlockSpec((1, 1, 1, TL),
+                               lambda i, j, t, pids: (i, j, 0, t)),
     )
-    return pl.pallas_call(
-        _make_kernel(L),
+    d = pl.pallas_call(
+        _kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((Q, P, L), jnp.float32),
-                   jax.ShapeDtypeStruct((Q, P, L), jnp.int32)],
+        out_shape=jax.ShapeDtypeStruct((Q, P, 1, max_len), jnp.float32),
         interpret=interpret,
-    )(probe_ids, luts, list_codes, list_ids)
+    )(probe_ids, jnp.swapaxes(luts, 2, 3), list_codes)
+    return list_topk(d[:, :, 0, :], list_ids, probe_ids, L)

@@ -1,13 +1,14 @@
 """Public jit'd wrappers over the Pallas kernels.
 
-Handles: lane-width padding (d -> multiple of 128, the H3 alignment
-analogue), tile padding of Q/B, interpret-mode auto-detection (CPU backend
-runs kernels in interpret mode for validation; real TPU compiles Mosaic),
-and masking of CSR -1 padding.
+Handles: lane-width padding for the tiled batch_dist matmul (d -> multiple
+of 128, the H3 alignment analogue), tile padding of Q/B, interpret-mode
+auto-detection (the CPU backend runs kernels in interpret mode for
+validation; a TPU compiles them through Mosaic), and clamping of the list
+scans' per-list depth L to the padded list length. The row-gather kernels
+take any d (their (1, 1, d) row blocks span the whole row), so the corpus
+and codes reach them unpadded.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +19,6 @@ from repro.kernels import gather_dist as _gd
 from repro.kernels import ivf_scan as _iv
 from repro.kernels import pq4_scan as _p4
 from repro.kernels import pq_adc as _pq
-from repro.kernels import traverse_step as _ts
 
 LANE = 128
 
@@ -51,9 +51,7 @@ def batch_dist(q: jnp.ndarray, x: jnp.ndarray, *, metric: str = "l2",
 def gather_dist(q: jnp.ndarray, db: jnp.ndarray, ids: jnp.ndarray, *,
                 metric: str = "l2") -> jnp.ndarray:
     """(Q, d), (n, d), (Q, M) -> (Q, M); -1 ids produce +inf."""
-    qp = _pad_dim(q, 1, LANE)
-    dbp = _pad_dim(db, 1, LANE)
-    return _gd.gather_dist(qp, dbp, ids, metric=metric, interpret=_on_cpu())
+    return _gd.gather_dist(q, db, ids, metric=metric, interpret=_on_cpu())
 
 
 def pq_adc(lut: jnp.ndarray, codes: jnp.ndarray, ids: jnp.ndarray
@@ -71,8 +69,7 @@ def pq4_adc(lut: jnp.ndarray, packed: jnp.ndarray, ids: jnp.ndarray
 def bin_dist(qcodes: jnp.ndarray, codes: jnp.ndarray, ids: jnp.ndarray
              ) -> jnp.ndarray:
     """(Q, nw) u32 packed queries, (n, nw) u32 packed codes, (Q, B) ->
-    (Q, B) exact Hamming; -1 ids produce +inf. No lane padding: the packed
-    word axis is tiny (d=128 -> nw=4) and the kernel reduces it wholesale."""
+    (Q, B) exact Hamming; -1 ids produce +inf."""
     return _bh.bin_dist(qcodes, codes, ids, interpret=_on_cpu())
 
 
@@ -80,14 +77,9 @@ def sq_gather_dist(q: jnp.ndarray, codes: jnp.ndarray, scale: jnp.ndarray,
                    zero: jnp.ndarray, ids: jnp.ndarray, *,
                    metric: str = "l2") -> jnp.ndarray:
     """Fused SQ gather+dequant+distance: (Q, d), (n, d) u8, (d,), (d,),
-    (Q, M) -> (Q, M); -1 ids produce +inf. Padding keeps the dequant exact:
-    padded columns get scale=0/zero=0 so they dequantize to 0, matching the
-    zero-padded query columns."""
-    qp = _pad_dim(q, 1, LANE)
-    cp = _pad_dim(codes, 1, LANE)
-    sp = _pad_dim(scale.reshape(1, -1), 1, LANE)
-    zp = _pad_dim(zero.reshape(1, -1), 1, LANE)
-    return _gd.sq_gather_dist(qp, cp, sp, zp, ids, metric=metric,
+    (Q, M) -> (Q, M); -1 ids produce +inf."""
+    return _gd.sq_gather_dist(q, codes, scale.reshape(1, -1),
+                              zero.reshape(1, -1), ids, metric=metric,
                               interpret=_on_cpu())
 
 
@@ -95,39 +87,32 @@ def fused_expand(q: jnp.ndarray, db: jnp.ndarray, ids: jnp.ndarray, *,
                  metric: str = "l2", L: int, n_beam: int = 1):
     """Fused beam-expansion step over full vectors (DESIGN.md §2):
     (Q, d), (n, d), (Q, C) ids -> (sorted dists (Q, T), ids (Q, T),
-    per-expansion bests (Q, n_beam)) with T = min(L, C); -1 ids -> +inf.
-    On real hardware keep T a power of two (in-kernel sort lowers via a
-    bitonic network, as with ivf_scan's top_k)."""
-    qp = _pad_dim(q, 1, LANE)
-    dbp = _pad_dim(db, 1, LANE)
-    return _ts.fused_expand(qp, dbp, ids, metric=metric, L=L,
+    per-expansion bests (Q, n_beam), earlier-expansion tie counts
+    (Q, n_beam)) with T = min(L, C); -1 ids -> +inf."""
+    return _gd.fused_expand(q, db, ids, metric=metric, L=L,
                             n_beam=n_beam, interpret=_on_cpu())
 
 
 def fused_expand_sq(q: jnp.ndarray, codes: jnp.ndarray, scale: jnp.ndarray,
                     zero: jnp.ndarray, ids: jnp.ndarray, *,
                     metric: str = "l2", L: int, n_beam: int = 1):
-    """SQ twin of fused_expand; same zero-exact padding as sq_gather_dist
-    (padded columns dequantize to 0, matching zero-padded query columns)."""
-    qp = _pad_dim(q, 1, LANE)
-    cp = _pad_dim(codes, 1, LANE)
-    sp = _pad_dim(scale.reshape(1, -1), 1, LANE)
-    zp = _pad_dim(zero.reshape(1, -1), 1, LANE)
-    return _ts.fused_expand_sq(qp, cp, sp, zp, ids, metric=metric, L=L,
+    """SQ twin of fused_expand."""
+    return _gd.fused_expand_sq(q, codes, scale.reshape(1, -1),
+                               zero.reshape(1, -1), ids, metric=metric, L=L,
                                n_beam=n_beam, interpret=_on_cpu())
 
 
 def fused_expand_pq(lut: jnp.ndarray, codes: jnp.ndarray, ids: jnp.ndarray,
                     *, L: int, n_beam: int = 1):
     """PQ-ADC twin of fused_expand: (Q, m, K) luts, (n, m) u8 codes."""
-    return _ts.fused_expand_pq(lut, codes, ids, L=L, n_beam=n_beam,
+    return _pq.fused_expand_pq(lut, codes, ids, L=L, n_beam=n_beam,
                                interpret=_on_cpu())
 
 
 def fused_expand_pq4(lut: jnp.ndarray, packed: jnp.ndarray,
                      ids: jnp.ndarray, *, L: int, n_beam: int = 1):
     """PQ4 twin: (Q, m, 16) luts, (n, m//2) nibble-packed u8 codes."""
-    return _ts.fused_expand_pq4(lut, packed, ids, L=L, n_beam=n_beam,
+    return _p4.fused_expand_pq4(lut, packed, ids, L=L, n_beam=n_beam,
                                 interpret=_on_cpu())
 
 
@@ -141,38 +126,22 @@ def fused_expand_bin(qcodes: jnp.ndarray, codes: jnp.ndarray,
 def ivf_scan(luts: jnp.ndarray, list_codes: jnp.ndarray,
              list_ids: jnp.ndarray, probe_ids: jnp.ndarray, *, L: int):
     """(Q, Pl, m, K) luts (Pl in {1, P}), padded lists, (Q, P) probes ->
-    per-list top-L (dists, ids), each (Q, P, L'). L is clamped to the
-    padded list length; on real hardware it is also rounded up to a power
-    of two (Mosaic lowers the in-kernel top_k via bitonic sort), so L' may
-    exceed the request — callers merge/trim downstream and extra slots are
-    just more (possibly +inf) candidates."""
-    interp = _on_cpu()
-    L = min(L, list_ids.shape[1])
-    if not interp:
-        L = min(1 << (L - 1).bit_length(), list_ids.shape[1])
-    return _iv.ivf_scan(luts, list_codes, list_ids, probe_ids, L=L,
-                        interpret=interp)
+    per-list top-L (dists, ids), each (Q, P, L'), L' = min(L, max_len)."""
+    return _iv.ivf_scan(luts, list_codes, list_ids, probe_ids,
+                        L=min(L, list_ids.shape[1]), interpret=_on_cpu())
 
 
 def pq4_ivf_scan(luts: jnp.ndarray, list_codes: jnp.ndarray,
                  list_ids: jnp.ndarray, probe_ids: jnp.ndarray, *, L: int):
     """pq4 twin of ivf_scan: (Q, Pl, m, 16) luts, (nlist, max_len, m//2)
-    nibble-packed list codes. Same L clamping/rounding policy."""
-    interp = _on_cpu()
-    L = min(L, list_ids.shape[1])
-    if not interp:
-        L = min(1 << (L - 1).bit_length(), list_ids.shape[1])
-    return _p4.pq4_ivf_scan(luts, list_codes, list_ids, probe_ids, L=L,
-                            interpret=interp)
+    nibble-packed list codes. Same L clamping."""
+    return _p4.pq4_ivf_scan(luts, list_codes, list_ids, probe_ids,
+                            L=min(L, list_ids.shape[1]), interpret=_on_cpu())
 
 
 def bin_ivf_scan(qcodes: jnp.ndarray, list_codes: jnp.ndarray,
                  list_ids: jnp.ndarray, probe_ids: jnp.ndarray, *, L: int):
     """bin twin of ivf_scan: (Q, nw) u32 packed queries, (nlist, max_len,
-    nw) u32 packed list codes. Same L clamping/rounding policy."""
-    interp = _on_cpu()
-    L = min(L, list_ids.shape[1])
-    if not interp:
-        L = min(1 << (L - 1).bit_length(), list_ids.shape[1])
-    return _bh.bin_ivf_scan(qcodes, list_codes, list_ids, probe_ids, L=L,
-                            interpret=interp)
+    nw) u32 packed list codes. Same L clamping."""
+    return _bh.bin_ivf_scan(qcodes, list_codes, list_ids, probe_ids,
+                            L=min(L, list_ids.shape[1]), interpret=_on_cpu())

@@ -4,28 +4,27 @@ x86 fast-scan (and its ARM port, the "ARM 4-bit PQ" line of work) shrinks
 PQ sub-codebooks to 16 centroids so the whole (m, 16) lookup table fits in
 SIMD registers and the LUT gather becomes an in-register byte shuffle. The
 TPU analogue implemented here: the table is 16x smaller than 8-bit PQ's
-(m, 256), so it stays RESIDENT IN VMEM across the whole scan (no per-step
-LUT traffic), codes arrive nibble-packed (two per byte — half the DMA
-bytes of pq_adc), and the gather is the same one-hot MXU contraction with a
-16-wide, rather than 256-wide, contraction axis.
+(m, 256), so the one-hot select that replaces the gather runs over 16
+sublanes instead of 256, and codes arrive nibble-packed (two per byte —
+half the DMA bytes of pq_adc).
 
-Two kernels share the nibble-unpack + one-hot idiom:
+Three kernels share the nibble-unpack + one-hot idiom:
 
-  pq4_adc      — graph-path gather ADC, grid (Q, B): the packed code row of
-                 neighbor ids[q, b] is fetched by scalar-prefetch (H2, same
-                 mechanism as pq_adc/gather_dist) and scored against query
-                 q's VMEM-resident LUT.
-  pq4_ivf_scan — IVF list scan + per-list partial top-L, grid (Q, P): the
-                 pq4 twin of ivf_scan (same prefetch-driven list DMA, same
-                 in-kernel top-L partial reduction), consuming packed
-                 (nlist, max_len, m//2) list codes.
+  pq4_adc          — graph-path gather ADC, grid (Q, C): the packed code
+                     row of neighbor ids[q, b] is fetched by scalar
+                     prefetch (H2, same mechanism as pq_adc/gather_dist)
+                     and scored against query q's VMEM-resident LUT.
+  fused_expand_pq4 — the same gather, then the traversal's sorted-block
+                     epilogue in XLA.
+  pq4_ivf_scan     — IVF list scan, grid (Q, P, max_len // TL): the pq4
+                     twin of ivf_scan (same prefetch-driven list DMA, same
+                     per-list top-L in XLA after the kernel), consuming
+                     packed (nlist, max_len, m//2) list codes.
 
 Nibble layout (core/quantize.py: pq4_pack): byte j = subspace 2j in the low
-nibble, 2j+1 in the high nibble; the kernels unpack with a mask/shift pair
-and interleave back to (m,) code rows.
-
-NOTE: in-kernel top_k is interpret-exact on CPU; Mosaic lowers it via
-bitonic sort on real TPU — keep L a power of two there (ops.py rounds).
+nibble, 2j+1 in the high nibble. The kernels never interleave: the
+transposed table is split into its even and odd subspace columns, which
+the low and high nibbles of byte j index directly.
 """
 from __future__ import annotations
 
@@ -36,83 +35,114 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.gather_dist import put_lane
+from repro.kernels.ivf_scan import list_tile, list_topk
+from repro.kernels.ref import sorted_block_ref
+
 K4 = 16  # centroids per 4-bit sub-codebook
 
 
-def _unpack_rows(packed: jnp.ndarray) -> jnp.ndarray:
-    """(..., m//2) i32 packed bytes -> (..., m) i32 codes in [0, 16)."""
-    lo = packed & 0x0F
-    hi = (packed >> 4) & 0x0F
-    return jnp.stack([lo, hi], axis=-1).reshape(
-        packed.shape[:-1] + (2 * packed.shape[-1],))
+def _split_lut(lut: jnp.ndarray):
+    """(..., m, 16) tables -> transposed (..., 16, m//2) tables of the
+    even (low-nibble) and odd (high-nibble) subspaces."""
+    t = jnp.swapaxes(lut, -1, -2)
+    return t[..., 0::2], t[..., 1::2]
 
 
 # ------------------------------------------------------------ graph gather
-def _adc_kernel(idx_ref, lut_ref, code_ref, o_ref):
-    lut = lut_ref[...].astype(jnp.float32)        # (1, m, 16)
-    packed = code_ref[...].astype(jnp.int32)      # (1, m//2)
-    m, K = lut.shape[1], lut.shape[2]
-    code = _unpack_rows(packed[0])                # (m,)
-    onehot = (code[:, None] == jax.lax.broadcasted_iota(jnp.int32, (m, K), 1)
-              ).astype(jnp.float32)               # (m, 16)
-    o_ref[...] = jnp.sum(lut[0] * onehot).reshape(1, 1)
+def _adc_kernel(idx_ref, lo_ref, hi_ref, code_ref, o_ref):
+    lut_lo = lo_ref[0].astype(jnp.float32)           # (16, m//2)
+    lut_hi = hi_ref[0].astype(jnp.float32)
+    packed = code_ref[0].astype(jnp.int32)           # (1, m//2)
+    rows = jax.lax.broadcasted_iota(jnp.int32, lut_lo.shape, 0)
+    picked = (jnp.where(rows == (packed & 0x0F), lut_lo, 0.0)
+              + jnp.where(rows == ((packed >> 4) & 0x0F), lut_hi, 0.0))
+    total = jnp.sum(jnp.sum(picked, axis=0, keepdims=True), axis=1,
+                    keepdims=True)
+    put_lane(o_ref, pl.program_id(1), total)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def pq4_adc(lut: jnp.ndarray, packed: jnp.ndarray, ids: jnp.ndarray, *,
             interpret: bool = False) -> jnp.ndarray:
-    """(Q, m, 16) luts, (n, m//2) u8 packed codes, (Q, B) ids -> (Q, B) f32."""
+    """(Q, m, 16) luts, (n, m//2) u8 packed codes, (Q, C) ids -> (Q, C)
+    f32; ids < 0 come back +inf."""
     Q, m, K = lut.shape
     assert K == K4, K
     mh = packed.shape[1]
     assert mh * 2 == m, (mh, m)
-    B = ids.shape[1]
+    C = ids.shape[1]
     assert ids.shape[0] == Q
-    safe_ids = jnp.maximum(ids, 0)
-
+    lut_lo, lut_hi = _split_lut(lut)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(Q, B),
+        grid=(Q, C),
         in_specs=[
-            # LUT block depends only on q: it is DMA'd once per query row
-            # and stays VMEM-resident across the B inner steps
-            pl.BlockSpec((1, m, K), lambda i, j, idx_ref: (i, 0, 0)),
-            pl.BlockSpec((1, mh), lambda i, j, idx_ref: (idx_ref[i, j], 0)),
+            # LUT blocks depend only on q: DMA'd once per query row and
+            # VMEM-resident across the C inner steps
+            pl.BlockSpec((1, K4, mh), lambda i, j, idx: (i, 0, 0)),
+            pl.BlockSpec((1, K4, mh), lambda i, j, idx: (i, 0, 0)),
+            pl.BlockSpec((1, 1, mh),
+                         lambda i, j, idx: (jnp.maximum(idx[i, j], 0), 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j, idx_ref: (i, j)),
+        out_specs=pl.BlockSpec((1, 1, C), lambda i, j, idx: (i, 0, 0)),
     )
     out = pl.pallas_call(
         _adc_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Q, B), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((Q, 1, C), jnp.float32),
         interpret=interpret,
-    )(safe_ids, lut, packed)
-    return jnp.where(ids >= 0, out, jnp.inf)
+    )(ids, lut_lo, lut_hi, packed[:, None, :])
+    return jnp.where(ids >= 0, out[:, 0, :], jnp.inf)
+
+
+@functools.partial(jax.jit, static_argnames=("L", "n_beam", "interpret"))
+def fused_expand_pq4(lut: jnp.ndarray, packed: jnp.ndarray,
+                     ids: jnp.ndarray, *, L: int, n_beam: int = 1,
+                     interpret: bool = False):
+    """PQ4 twin of fused_expand: (Q, m, 16) luts, (n, m//2) nibble-packed
+    u8 codes -> the sorted candidate block (see gather_dist.fused_expand)."""
+    Q, m, K = lut.shape
+    C = ids.shape[1]
+    mh = packed.shape[1]
+    assert K == K4 and mh * 2 == m, (lut.shape, packed.shape)
+    assert ids.shape[0] == Q and C % n_beam == 0, (ids.shape, n_beam)
+    lut_lo, lut_hi = _split_lut(lut)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(Q, C),
+        in_specs=[
+            pl.BlockSpec((1, K4, mh), lambda i, j, idx: (i, 0, 0)),
+            pl.BlockSpec((1, K4, mh), lambda i, j, idx: (i, 0, 0)),
+            pl.BlockSpec((1, 1, mh),
+                         lambda i, j, idx: (jnp.maximum(idx[i, j], 0), 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, 1, C), lambda i, j, idx: (i, 0, 0)),
+    )
+    dist = pl.pallas_call(
+        _adc_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((Q, 1, C), jnp.float32),
+        interpret=interpret,
+    )(ids, lut_lo, lut_hi, packed[:, None, :])
+    return sorted_block_ref(dist[:, 0, :], ids, L, n_beam)
 
 
 # ---------------------------------------------------------------- IVF scan
-def _make_scan_kernel(L: int):
-    def _kernel(pids_ref, lut_ref, codes_ref, ids_ref, od_ref, oi_ref):
-        lut = lut_ref[0, 0].astype(jnp.float32)          # (m, 16)
-        packed = codes_ref[0].astype(jnp.int32)          # (max_len, m//2)
-        ids = ids_ref[0]                                 # (max_len,)
-        m, K = lut.shape
-        max_len = packed.shape[0]
-        codes = _unpack_rows(packed)                     # (max_len, m)
-        # gather-as-matmul: onehot (max_len, m*16) @ lut (m*16, 1) — the
-        # contraction axis is 16x shorter than ivf_scan's, same MXU idiom
-        onehot = (codes[:, :, None]
-                  == jax.lax.broadcasted_iota(jnp.int32, (max_len, m, K), 2)
-                  ).astype(jnp.float32)
-        d = jax.lax.dot_general(
-            onehot.reshape(max_len, m * K), lut.reshape(m * K, 1),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)[:, 0]    # (max_len,)
-        d = jnp.where(ids >= 0, d, jnp.inf)
-        neg, pos = jax.lax.top_k(-d, L)
-        od_ref[0, 0] = -neg
-        oi_ref[0, 0] = jnp.where(jnp.isfinite(neg), ids[pos], -1)
-    return _kernel
+def _scan_kernel(pids_ref, lo_ref, hi_ref, codes_ref, o_ref):
+    lut_lo = lo_ref[0, 0].astype(jnp.float32)            # (16, m//2)
+    lut_hi = hi_ref[0, 0].astype(jnp.float32)
+    packed = codes_ref[0].astype(jnp.int32).T            # (m//2, TL)
+    mh = packed.shape[0]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (K4, packed.shape[1]), 0)
+    acc = jnp.zeros((1, packed.shape[1]), jnp.float32)
+    for j in range(mh):
+        byte = packed[j:j + 1, :]
+        picked = (jnp.where(rows == (byte & 0x0F), lut_lo[:, j:j + 1], 0.0)
+                  + jnp.where(rows == ((byte >> 4) & 0x0F),
+                              lut_hi[:, j:j + 1], 0.0))
+        acc = acc + jnp.sum(picked, axis=0, keepdims=True)
+    o_ref[0, 0] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("L", "interpret"))
@@ -135,26 +165,26 @@ def pq4_ivf_scan(luts: jnp.ndarray, list_codes: jnp.ndarray,
     assert Pl in (1, P), (Pl, P)
     assert list_ids.shape == (nlist, max_len)
     assert L <= max_len, (L, max_len)
-    lut_j = (lambda i, j, pids: (i, j, 0, 0)) if Pl == P else \
-        (lambda i, j, pids: (i, 0, 0, 0))
+    TL = list_tile(max_len)
+    lut_j = (lambda i, j, t, pids: (i, j, 0, 0)) if Pl == P else \
+        (lambda i, j, t, pids: (i, 0, 0, 0))
+    lut_lo, lut_hi = _split_lut(luts)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(Q, P),
+        grid=(Q, P, max_len // TL),
         in_specs=[
-            pl.BlockSpec((1, 1, m, K), lut_j),
-            pl.BlockSpec((1, max_len, mh), lambda i, j, pids: (pids[i, j], 0, 0)),
-            pl.BlockSpec((1, max_len), lambda i, j, pids: (pids[i, j], 0)),
+            pl.BlockSpec((1, 1, K4, mh), lut_j),
+            pl.BlockSpec((1, 1, K4, mh), lut_j),
+            pl.BlockSpec((1, TL, mh), lambda i, j, t, pids: (pids[i, j], t, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, L), lambda i, j, pids: (i, j, 0)),
-            pl.BlockSpec((1, 1, L), lambda i, j, pids: (i, j, 0)),
-        ],
+        out_specs=pl.BlockSpec((1, 1, 1, TL),
+                               lambda i, j, t, pids: (i, j, 0, t)),
     )
-    return pl.pallas_call(
-        _make_scan_kernel(L),
+    d = pl.pallas_call(
+        _scan_kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((Q, P, L), jnp.float32),
-                   jax.ShapeDtypeStruct((Q, P, L), jnp.int32)],
+        out_shape=jax.ShapeDtypeStruct((Q, P, 1, max_len), jnp.float32),
         interpret=interpret,
-    )(probe_ids, luts, list_codes, list_ids)
+    )(probe_ids, lut_lo, lut_hi, list_codes)
+    return list_topk(d[:, :, 0, :], list_ids, probe_ids, L)

@@ -196,14 +196,15 @@ def ivf_scan_ref(luts: jnp.ndarray, list_codes: jnp.ndarray,
     """
     import jax
 
-    P = probe_ids.shape[1]
-    if luts.shape[1] == 1 and P > 1:
-        luts = jnp.broadcast_to(luts, (luts.shape[0], P) + luts.shape[2:])
-    codes = list_codes[probe_ids].astype(jnp.int32)   # (Q, P, max_len, m)
+    codes = list_codes[probe_ids]                     # (Q, P, max_len, m)
     ids = list_ids[probe_ids]                         # (Q, P, max_len)
-    g = jnp.take_along_axis(luts[:, :, None, :, :],   # (Q, P, 1, m, K)
-                            codes[..., None], axis=-1)[..., 0]
-    d = jnp.sum(g, axis=-1)                           # (Q, P, max_len)
+    # one subspace at a time: a (Q, P, max_len) table read per j, never a
+    # (Q, P, max_len, m) f32 temporary (1.2 GB at a 256-query bucket, 24
+    # probes and 3k-long lists)
+    d = jnp.zeros(ids.shape, jnp.float32)
+    for j in range(codes.shape[-1]):
+        d = d + jnp.take_along_axis(luts[:, :, j, :],
+                                    codes[..., j].astype(jnp.int32), axis=-1)
     d = jnp.where(ids >= 0, d, jnp.inf)
     neg, pos = jax.lax.top_k(-d, L)
     out_ids = jnp.take_along_axis(ids, pos, axis=-1)

@@ -33,7 +33,7 @@ from pathlib import Path
 import jax
 
 from repro import configs as cfg_registry
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import build_cell
 
 ART_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
@@ -97,7 +97,7 @@ def _lower_metrics(arch, shape, mesh, depth, unroll, variant="baseline"):
     """Compile a depth/unroll variant and pull (flops, bytes, coll_bytes)."""
     cell = build_cell(arch, shape, mesh, depth=depth, unroll=unroll,
                       variant=variant)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(cell.step_fn, in_shardings=cell.in_shardings) \
             .lower(*cell.args).compile()
         cost = compiled.cost_analysis() or {}
@@ -136,7 +136,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, save: bool = True,
     mesh_name = "pod2x16x16" if multi_pod else "pod16x16"
     t0 = time.time()
     cell = build_cell(arch, shape, mesh, variant=variant)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(cell.step_fn, in_shardings=cell.in_shardings)
         lowered = jitted.lower(*cell.args)
         compiled = lowered.compile()

@@ -78,9 +78,21 @@ def serve_ann(n: int, shards: int = 1, beam: int = 1):
     t0 = time.perf_counter()
     report = serve_loop(engines, requests)
     dt = time.perf_counter() - t0
-    print(f"{report.summary()} | wall {dt*1e3:.1f} ms (CPU interpret)")
+    print(f"{report.summary()} | wall {dt*1e3:.1f} ms ({device_label()})")
     for name, st in sorted(report.engine_stats.items()):
         print(f"  [{name}] {st.summary()}")
+    for r in report.results:
+        if r.status == "failed":
+            print(f"  request {r.request_id} ({r.engine}) failed: {r.error}")
+    return report
+
+
+def device_label() -> str:
+    """What the timings ran on, as JAX reports it (Pallas kernels run in
+    interpret mode on a CPU platform)."""
+    d = jax.devices()[0]
+    mode = ", Pallas interpret mode" if d.platform == "cpu" else ""
+    return f"{d.platform} {d.device_kind} x{len(jax.devices())}{mode}"
 
 
 def serve_lm(arch: str):
@@ -98,7 +110,7 @@ def serve_lm(arch: str):
         logits, cache = step(p, cache, nxt)
     jax.block_until_ready(logits)
     dt = (time.perf_counter() - t0) / 16
-    print(f"{arch}: {dt*1e3:.2f} ms/token (smoke config, CPU), "
+    print(f"{arch}: {dt*1e3:.2f} ms/token (smoke config, {device_label()}), "
           f"cache len={int(cache['len'][0])}")
 
 
@@ -112,8 +124,15 @@ def main():
     ap.add_argument("--shards", type=int, default=1,
                     help="ShardedKBest mesh size for --mode ann (1 = plain)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.mode == "ann":
-        serve_ann(args.n, shards=args.shards, beam=args.beam)
+        report = serve_ann(args.n, shards=args.shards, beam=args.beam)
+        if report.n_failed:
+            # a failed dispatch (e.g. a kernel the compiler refused) is
+            # caught per request by serve_loop's error boundary — it must
+            # still fail the launcher
+            raise SystemExit(f"{report.n_failed} request(s) failed")
     else:
         serve_lm(args.arch)
 

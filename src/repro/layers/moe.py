@@ -24,7 +24,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.layers.common import act_fn
 
 
@@ -308,7 +307,7 @@ def moe_ffn_shardmap(params: dict, x: jnp.ndarray, cfg: MoEConfig
         out_l = jax.lax.all_gather(out_s, tp, axis=0, tiled=True)  # (T_l, d)
         return out_l, aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         block,
         in_specs=(P(tok, None), P(), P(ep, tp, None), P(ep, tp, None),
                   P(ep, tp, None)),

@@ -23,7 +23,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.layers import common as L
 
 
@@ -376,7 +375,7 @@ def serve_retrieval_shardmap(params: dict, batch: dict, cfg: RecsysConfig,
         mneg, pos = jax.lax.top_k(flat_neg, k)
         return -mneg, jnp.take_along_axis(flat_ids, pos, axis=1)
 
-    fn = shard_map(local, mesh=mesh,
+    fn = jax.shard_map(local, mesh=mesh,
                        in_specs=(P(), P(axis, None)),
                        out_specs=(P(), P()), check_vma=False)
     return fn(q, cands)

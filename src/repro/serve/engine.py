@@ -33,6 +33,7 @@ than the search itself. The engine removes that cost structurally:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Dict, Optional, Sequence, Tuple
 
@@ -164,16 +165,18 @@ class SearchEngine:
         fn = self._cache.get(key)
         if fn is None:
             self.cache_misses += 1
-            index = self.index
 
-            def run(q, mask):
+            def run(index, q, mask):
                 # Python side effect: executes once per XLA trace, never on
                 # cached executions — this IS the trace counter.
                 self.n_traces += 1
                 return index.search_padded(q, mask, search_cfg=scfg,
                                            with_stats=True)
 
-            fn = jax.jit(run)
+            # the index is an ARGUMENT (KBest/ShardedKBest are pytrees):
+            # its arrays stay where they are on the device and the program
+            # reads them as operands instead of embedding them as constants
+            fn = functools.partial(jax.jit(run), self.index)
             self._cache[key] = fn
         else:
             self.cache_hits += 1

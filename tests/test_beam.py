@@ -158,9 +158,9 @@ def indexes(ds):
 
 
 def _traversal_operands(idx, scfg, queries):
-    """The (graph, queries-operand, entry_ids, dist_fn, cfg) a KBest search
-    hands to core.search for its quant family (white-box, mirrors
-    _search_impl so the seed port can be driven identically)."""
+    """The (graph, queries-operand, entry_ids, (dist_fn, data), cfg) a
+    KBest search hands to core.search for its quant family (white-box,
+    mirrors _search_impl so the seed port can be driven identically)."""
     from repro.core import quantize as qz
     ds_q = jnp.asarray(queries)
     cfg = idx.config
@@ -191,12 +191,13 @@ def test_w1_bit_parity_vs_seed(indexes, ds, quant, visited_mode):
     ids and every stat — for every quant family and visited mode."""
     idx = indexes[quant]
     scfg = dataclasses.replace(idx.config.search, visited_mode=visited_mode)
-    graph, op, entries, dist_fn, cfg = _traversal_operands(idx, scfg,
-                                                           ds.queries)
+    graph, op, entries, (dist_fn, data), cfg = _traversal_operands(
+        idx, scfg, ds.queries)
     n = idx.db.shape[0]
-    d0, i0, st0 = _seed_search(graph, op, entries, dist_fn, cfg, n)
-    d1, i1, st1 = search_mod.search(graph, op, entries, dist_fn=dist_fn,
-                                    cfg=cfg, n_total=n)
+    d0, i0, st0 = _seed_search(graph, op, entries,
+                               lambda q, i: dist_fn(data, q, i), cfg, n)
+    d1, i1, st1 = search_mod.search(graph, op, entries, data,
+                                    dist_fn=dist_fn, cfg=cfg, n_total=n)
     assert np.array_equal(np.asarray(i0), np.asarray(i1))
     for a, b in zip(st0, st1):
         assert np.array_equal(np.asarray(a), np.asarray(b))
@@ -543,3 +544,34 @@ if HAVE_HYPOTHESIS:
     @given(st.integers(2, 24), st.integers(1, 6), st.integers(0, 2 ** 30))
     def test_pick_top_w_first_unvisited(L, w, seed):
         _check_pick_top_w(L, w, seed)
+
+
+@pytest.mark.parametrize("W", [1, 4])
+@pytest.mark.parametrize("visited_mode", ["queue", "bitmap"])
+@pytest.mark.parametrize("quant", ["none", "pq"])
+def test_lane_results_independent_of_batch(indexes, ds, quant, visited_mode,
+                                           W):
+    """Lockstep lanes never interact: searching a batch whole or in two
+    uneven parts gives every lane the same dists, ids and per-lane stats
+    (the build's chunked searches and the engine's padded buckets rely on
+    it)."""
+    idx = indexes[quant]
+    scfg = dataclasses.replace(idx.config.search, visited_mode=visited_mode,
+                               beam_width=W, early_term=True)
+    graph, op, entries, (dist_fn, data), cfg = _traversal_operands(
+        idx, scfg, ds.queries)
+    nq = op.shape[0]
+    vm = jnp.asarray(np.arange(nq) % 5 != 3)            # some padded lanes
+
+    def run(sl):
+        d, i, st = search_mod.search(
+            graph, op[sl], entries, data, dist_fn=dist_fn, cfg=cfg,
+            n_total=idx.db.shape[0], valid_mask=vm[sl])
+        return [d, i, st.n_hops, st.n_dist, st.early_terminated]
+
+    whole = run(slice(None))
+    cut = nq // 3
+    parts = [np.concatenate([np.asarray(a), np.asarray(b)])
+             for a, b in zip(run(slice(0, cut)), run(slice(cut, None)))]
+    for a, b in zip(whole, parts):
+        assert np.array_equal(np.asarray(a), b)

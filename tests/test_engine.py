@@ -182,12 +182,12 @@ def test_bitmap_parity_with_colliding_entry_seeds(tiny_ds, tiny_index):
     e = tiny_index.entry
     n = tiny_index.db.shape[0]
     seeds = jnp.array([e, e, (e + 7) % n, (e + 7) % n, e], jnp.int32)
-    dist_fn = tiny_index._get_dist_fn("full", "ref")
+    dist_fn, data = tiny_index._get_dist_fn("full", "ref")
     out = {}
     for mode in ("queue", "bitmap"):
         cfg = SearchConfig(L=256, k=10, early_term=False, visited_mode=mode)
         d, ids, _ = search_mod.search(
-            tiny_index.graph, jnp.asarray(tiny_ds.queries), seeds,
+            tiny_index.graph, jnp.asarray(tiny_ds.queries), seeds, data,
             dist_fn=dist_fn, cfg=cfg, n_total=n)
         out[mode] = np.asarray(ids)
     np.testing.assert_array_equal(out["bitmap"], out["queue"])

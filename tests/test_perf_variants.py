@@ -12,7 +12,7 @@ from repro import configs as reg
 def test_lm_sharded_loss_matches_baseline():
     """loss_vocab_axis path == naive path (same logits, different softmax
     factorization) on a 1-device mesh."""
-    from repro.launch.mesh import make_test_mesh, mesh_context
+    from repro.launch.mesh import make_test_mesh
     from repro.models import transformer as T
     cfg = reg.get("gemma_2b").smoke_config()
     p = T.init_params(cfg, jax.random.PRNGKey(0))
@@ -23,7 +23,7 @@ def test_lm_sharded_loss_matches_baseline():
                                loss_batch_axes=("data",),
                                loss_vocab_shards=2)
     mesh = make_test_mesh()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         l1, _ = jax.jit(lambda p, b: T.loss_fn(p, b, cfg2))(p, batch)
     np.testing.assert_allclose(float(l0), float(l1), rtol=2e-5)
 
@@ -46,7 +46,7 @@ def test_bert4rec_masked_loss_matches_full():
 
 
 def test_retrieval_shardmap_matches_naive():
-    from repro.launch.mesh import make_test_mesh, mesh_context
+    from repro.launch.mesh import make_test_mesh
     from repro.models import recsys as R
     cfg = reg.get("bst").smoke_config()
     p = R.init_params(cfg, jax.random.PRNGKey(0))
@@ -117,8 +117,9 @@ def test_moe_shardmap_matches_reference():
         pytest.skip("needs 4 host devices (run tests with "
                     "XLA_FLAGS=--xla_force_host_platform_device_count=4)")
     from repro.layers.moe import MoEConfig, init_moe, moe_ffn, moe_ffn_shardmap
-    from repro.launch.mesh import mesh_context
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     cfg0 = MoEConfig(n_experts=4, top_k=2, d_ff_expert=16, capacity_factor=8.0)
     cfg1 = dataclasses.replace(cfg0, ep_axis="data", tp_axis="model",
                                token_axes=("data",), use_shardmap=True,
@@ -126,7 +127,7 @@ def test_moe_shardmap_matches_reference():
     p = init_moe(jax.random.PRNGKey(0), 8, cfg0, dtype=jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (16, 8))
     out0, _ = moe_ffn(p, x, cfg0)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         out1, _ = jax.jit(lambda p, x: moe_ffn_shardmap(p, x, cfg1))(p, x)
     np.testing.assert_allclose(np.asarray(out0), np.asarray(out1),
                                rtol=1e-5, atol=1e-5)

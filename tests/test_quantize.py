@@ -98,9 +98,10 @@ def test_sq_kernel_impl_routes_through_kernel_and_matches(monkeypatch):
     monkeypatch.setattr(kops, "sq_gather_dist", spy)
     for metric in ("l2", "ip"):
         called.clear()
-        out_r = qz.sq_make_dist_fn(codes, st, metric, impl="ref")(q, ids)
+        out_r = qz.sq_make_dist_fn(metric, impl="ref")((codes, st), q, ids)
         assert "kernel" not in called
-        out_k = qz.sq_make_dist_fn(codes, st, metric, impl="kernel")(q, ids)
+        out_k = qz.sq_make_dist_fn(metric, impl="kernel")((codes, st), q,
+                                                           ids)
         assert called.get("kernel"), "impl='kernel' must hit the kernel path"
         np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                    rtol=3e-5, atol=3e-4)

@@ -76,10 +76,10 @@ def test_distributed_search_parity(deep_ds, deep_index):
     d_sh, i_sh = fn(db, graph, entries, queries)
 
     from repro.core import search as smod
-    dist_fn = smod.make_dist_fn(deep_index.db, "ip", "ref")
+    dist_fn = smod.make_dist_fn("ip", "ref")
     d_loc, i_loc, _ = smod.search(
         deep_index.graph, jnp.asarray(deep_ds.queries),
-        jnp.array([deep_index.entry], jnp.int32),
+        jnp.array([deep_index.entry], jnp.int32), deep_index.db,
         dist_fn=dist_fn, cfg=cfg, n_total=n)
     assert np.array_equal(np.asarray(i_sh), np.asarray(i_loc))
 
