@@ -250,11 +250,13 @@ class KBest:
         quant = cfg.quant.kind
 
         if quant in ("pq", "pq4"):
-            if quant == "pq":
-                tables = qz.pq_query_tables(self.pq.codebooks, q, metric)
-            else:
-                tables = qz.pq4_query_tables(self.pq.codebooks, q, metric,
-                                             lut_u8=cfg.quant.pq4_lut_u8)
+            with jax.named_scope("kbest.lut"):
+                if quant == "pq":
+                    tables = qz.pq_query_tables(self.pq.codebooks, q, metric)
+                else:
+                    tables = qz.pq4_query_tables(
+                        self.pq.codebooks, q, metric,
+                        lut_u8=cfg.quant.pq4_lut_u8)
             wide = _widen(scfg)
             dist_fn, data = self._get_dist_fn(quant, scfg.dist_impl)
             dists, ids, stats = search_mod.search(
@@ -278,7 +280,8 @@ class KBest:
             # two-stage rescore (DESIGN.md §14): traverse under packed
             # Hamming with the queue widened to hold rescore_factor*k
             # candidates, then exact re-rank that overfetch
-            qcodes = qz.bin_query_codes(self.bin, q)
+            with jax.named_scope("kbest.lut"):
+                qcodes = qz.bin_query_codes(self.bin, q)
             wide = _widen_bin(scfg)
             dist_fn, data = self._get_dist_fn("bin", scfg.dist_impl)
             dists, ids, stats = search_mod.search(
@@ -379,18 +382,19 @@ class KBest:
         cross-family n_dist accounting)."""
         r = rerank if rerank > 0 else min(4 * k, ids.shape[1])
         r = min(max(r, k), ids.shape[1])   # never fewer candidates than k
-        cand = ids[:, :r]
-        if impl == "kernel":
-            from repro.kernels import ops as kops
-            d = kops.gather_dist(q, self.db, cand, metric=metric)
-        else:
-            vecs = self.db[jnp.maximum(cand, 0)]
-            from repro.core.distance import batched_one_to_many
-            d = batched_one_to_many(q, vecs, metric)
-        d = jnp.where(cand >= 0, d, jnp.inf)
-        neg, pos = jax.lax.top_k(-d, k)
-        n_exact = jnp.sum(cand >= 0, axis=1).astype(jnp.int32)
-        return -neg, jnp.take_along_axis(cand, pos, axis=1), n_exact
+        with jax.named_scope("kbest.rerank"):
+            cand = ids[:, :r]
+            if impl == "kernel":
+                from repro.kernels import ops as kops
+                d = kops.gather_dist(q, self.db, cand, metric=metric)
+            else:
+                vecs = self.db[jnp.maximum(cand, 0)]
+                from repro.core.distance import batched_one_to_many
+                d = batched_one_to_many(q, vecs, metric)
+            d = jnp.where(cand >= 0, d, jnp.inf)
+            neg, pos = jax.lax.top_k(-d, k)
+            n_exact = jnp.sum(cand >= 0, axis=1).astype(jnp.int32)
+            return -neg, jnp.take_along_axis(cand, pos, axis=1), n_exact
 
     # ------------------------------------------------------------ save/load
     def save(self, path: str, _label: str = "index") -> None:

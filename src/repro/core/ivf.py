@@ -240,17 +240,23 @@ def search_ivf(state: IVFState, q: jnp.ndarray, nprobe: int, L: int,
     dense multi-candidate expansion — every probed list is a "beam slot"
     of max_len candidates — so results are identical for any beam_width
     (pinned in tests/test_beam.py) and only (L, nprobe, dist_impl,
-    quant) key its behavior.
+    quant) key its behavior. The stages carry the named scopes
+    `kbest.probe`, `kbest.lut` and `kbest.scan`.
     """
-    probes = select_probes(state, q, nprobe, metric)
+    with jax.named_scope("kbest.probe"):
+        probes = select_probes(state, q, nprobe, metric)
     if state.bin is not None:
         # bin codec: one packed query encoding serves every probed list
         # (no per-probe LUT machinery — DESIGN.md §14)
-        qcodes = qz.bin_query_codes(state.bin, q)
-        dists, ids = scan_bin_lists(state, qcodes, probes, L, impl)
+        with jax.named_scope("kbest.lut"):
+            qcodes = qz.bin_query_codes(state.bin, q)
+        with jax.named_scope("kbest.scan"):
+            dists, ids = scan_bin_lists(state, qcodes, probes, L, impl)
         return dists, ids, probes
-    luts, bias = query_luts(state, q, probes, metric, lut_u8=lut_u8)
-    dists, ids = scan_lists(state, luts, probes, L, impl, bias=bias)
+    with jax.named_scope("kbest.lut"):
+        luts, bias = query_luts(state, q, probes, metric, lut_u8=lut_u8)
+    with jax.named_scope("kbest.scan"):
+        dists, ids = scan_lists(state, luts, probes, L, impl, bias=bias)
     return dists, ids, probes
 
 

@@ -33,6 +33,12 @@ kernel path under a beam routes through the `fused_expand` family
 (kernels/gather_dist.py and twins): the gather + distance kernel followed
 by the block sort, emitting the sorted candidate block directly.
 
+Named scopes, for a device trace (each bucket's compiled HLO maps an op to
+its scope): `kbest.seed` (entry distances, queue init), `kbest.expand`
+(neighbour gather, dedupe, visited and in-queue filters, distances) and
+`kbest.queue` (pick and mark, merge of the candidate block, Eq. 3, the
+final top-k); the callers add `kbest.lut` and `kbest.rerank`.
+
 Visited-set semantics: "bitmap" mode implements Algorithm 1 exactly (a
 packed per-query bitmap of distance-computed nodes, O(n/8) bytes/query);
 "queue" mode (default) dedupes only against the candidate queue, which may
@@ -181,115 +187,133 @@ def search(
     use_fused = expand_fn is not None and cfg.batch_B == 0
 
     # ---- init: seed the queue with the entry points -----------------------
-    e_ids = jnp.broadcast_to(entry_ids[None, :], (Q, entry_ids.shape[0]))
-    e_dists = dist_fn(data, queries, e_ids)
-    q0 = qmod.init_queue(L)
-    q0 = jax.tree.map(lambda x: jnp.broadcast_to(x[None], (Q,) + x.shape), q0)
+    with jax.named_scope("kbest.seed"):
+        e_ids = jnp.broadcast_to(entry_ids[None, :],
+                                 (Q, entry_ids.shape[0]))
+        e_dists = dist_fn(data, queries, e_ids)
+        q0 = qmod.init_queue(L)
+        q0 = jax.tree.map(
+            lambda x: jnp.broadcast_to(x[None], (Q,) + x.shape), q0)
 
-    def _seed(qq, nd, ni):
-        out, _, _ = qmod.merge_insert(qq, nd, ni)
-        return out
+        def _seed(qq, nd, ni):
+            out, _, _ = qmod.merge_insert(qq, nd, ni)
+            return out
 
-    queue = jax.vmap(_seed)(qmod.Queue(q0[0], q0[1], q0[2]), e_dists, e_ids)
-    bitmap = jnp.zeros((Q, nwords), dtype=jnp.uint32)
-    if cfg.visited_mode == "bitmap":
-        bitmap = jax.vmap(_bitmap_set)(bitmap, e_ids)
+        queue = jax.vmap(_seed)(qmod.Queue(q0[0], q0[1], q0[2]), e_dists,
+                                e_ids)
+        bitmap = jnp.zeros((Q, nwords), dtype=jnp.uint32)
+        if cfg.visited_mode == "bitmap":
+            bitmap = jax.vmap(_bitmap_set)(bitmap, e_ids)
 
-    # seed distances count: the init dist_fn call above already computed one
-    # distance per valid entry seed — starting ndist at 0 undercounted every
-    # family's n_dist by n_entries (benchmarks, EngineStats dists/query).
-    # Gated on the lane being valid: padded lanes must keep the documented
-    # "invalid lanes add no distance computations" invariant.
-    active0 = (jnp.ones((Q,), bool) if valid_mask is None
-               else valid_mask.astype(bool))
-    n_seed = jnp.where(active0,
-                       jnp.sum(e_ids >= 0, axis=1), 0).astype(jnp.int32)
+        # seed distances count: the init dist_fn call above already
+        # computed one distance per valid entry seed — starting ndist at 0
+        # undercounted every family's n_dist by n_entries (benchmarks,
+        # EngineStats dists/query). Gated on the lane being valid: padded
+        # lanes must keep the documented "invalid lanes add no distance
+        # computations" invariant.
+        active0 = (jnp.ones((Q,), bool) if valid_mask is None
+                   else valid_mask.astype(bool))
+        n_seed = jnp.where(active0,
+                           jnp.sum(e_ids >= 0, axis=1), 0).astype(jnp.int32)
 
-    carry = _Carry(
-        dists=queue.dists, ids=queue.ids, visited=queue.visited,
-        bitmap=bitmap,
-        et_ctr=jnp.zeros((Q,), jnp.int32),
-        et_fired=jnp.zeros((Q,), bool),
-        active=active0,
-        hops=jnp.zeros((Q,), jnp.int32),
-        ndist=n_seed,
-        it=jnp.int32(0),
-    )
+        carry = _Carry(
+            dists=queue.dists, ids=queue.ids, visited=queue.visited,
+            bitmap=bitmap,
+            et_ctr=jnp.zeros((Q,), jnp.int32),
+            et_fired=jnp.zeros((Q,), bool),
+            active=active0,
+            hops=jnp.zeros((Q,), jnp.int32),
+            ndist=n_seed,
+            it=jnp.int32(0),
+        )
 
     def cond(c: _Carry):
         return jnp.any(c.active) & (c.it < cfg.hops_bound)
 
     def body(c: _Carry) -> _Carry:
         queue = qmod.Queue(c.dists, c.ids, c.visited)
-        # the W closest unvisited candidates per lane, by the sorted
-        # invariant (first W unvisited slots in queue order)
-        idxs, has = jax.vmap(lambda qq: qmod.pick_top_w(qq, W))(queue)
-        exp_w = c.active[:, None] & has                    # (Q, W)
-        any_has = jnp.any(has, axis=1)
-        exp_any = c.active & any_has
-        v = jnp.where(exp_w, queue.ids[jnp.arange(Q)[:, None], idxs], -1)
-        queue = jax.vmap(qmod.mark_visited_many)(queue, idxs, exp_w)
+        with jax.named_scope("kbest.queue"):
+            # the W closest unvisited candidates per lane, by the sorted
+            # invariant (first W unvisited slots in queue order)
+            idxs, has = jax.vmap(lambda qq: qmod.pick_top_w(qq, W))(queue)
+            exp_w = c.active[:, None] & has                    # (Q, W)
+            any_has = jnp.any(has, axis=1)
+            exp_any = c.active & any_has
+            v = jnp.where(exp_w, queue.ids[jnp.arange(Q)[:, None], idxs], -1)
+            queue = jax.vmap(qmod.mark_visited_many)(queue, idxs, exp_w)
 
-        # gather all W neighbor lists at once; -1 rows for idle slots/lanes.
-        # The flat (W*M,) row is deduped against EARLIER flat positions, so
-        # overlap between beam expansions collapses exactly like duplicates
-        # within one adjacency row (beam order = flat order).
-        nbrs = jnp.where(v[..., None] >= 0, graph[jnp.maximum(v, 0)], -1)
-        flat = jax.vmap(qmod.dedupe_ids)(nbrs.reshape(Q, W * M))
+        with jax.named_scope("kbest.expand"):
+            # gather all W neighbor lists at once; -1 rows for idle
+            # slots/lanes. The flat (W*M,) row is deduped against EARLIER
+            # flat positions, so overlap between beam expansions collapses
+            # exactly like duplicates within one adjacency row (beam order
+            # = flat order).
+            nbrs = jnp.where(v[..., None] >= 0, graph[jnp.maximum(v, 0)], -1)
+            flat = jax.vmap(qmod.dedupe_ids)(nbrs.reshape(Q, W * M))
 
-        bitmap = c.bitmap
-        if cfg.visited_mode == "bitmap":
-            seen = jax.vmap(_bitmap_test)(bitmap, flat)
-            flat = jnp.where(seen, -1, flat)
-            # flat ids are deduped (above) and seen-masked: raw scatter safe
-            bitmap = jax.vmap(_bitmap_set_raw)(bitmap, flat)
+            bitmap = c.bitmap
+            if cfg.visited_mode == "bitmap":
+                seen = jax.vmap(_bitmap_test)(bitmap, flat)
+                flat = jnp.where(seen, -1, flat)
+                # flat ids are deduped (above) and seen-masked: raw
+                # scatter safe
+                bitmap = jax.vmap(_bitmap_set_raw)(bitmap, flat)
 
-        n_new = jnp.sum(flat >= 0, axis=1).astype(jnp.int32)
+            n_new = jnp.sum(flat >= 0, axis=1).astype(jnp.int32)
 
-        # candidates already in the queue are masked BEFORE the distance
-        # computation (merge_insert would discard them anyway, so results
-        # are unchanged; the fused kernel path requires it, and n_new above
-        # keeps the historical "computed distances" accounting)
-        flat = jnp.where(jax.vmap(qmod.in_queue_mask)(queue, flat), -1, flat)
+            # candidates already in the queue are masked BEFORE the
+            # distance computation (merge_insert would discard them anyway,
+            # so results are unchanged; the fused kernel path requires it,
+            # and n_new above keeps the historical "computed distances"
+            # accounting)
+            flat = jnp.where(jax.vmap(qmod.in_queue_mask)(queue, flat), -1,
+                             flat)
 
-        # --- the 1-to-B (here Q-to-W·B) batched distance step (H1 + H2) ---
-        if use_fused:
-            sd, si, bests, ties = expand_fn(data, queries, flat)
-            merged = jax.vmap(qmod.merge_sorted_runs)(queue, sd, si)
-            ranks = jax.vmap(qmod.block_ranks)(queue, sd, bests, ties)
-        else:
-            nd = _dist_chunked(dist_fn, data, queries, flat,
-                               cfg.batch_B)
-            nd = jnp.where(flat >= 0, nd, jnp.inf)
-            # flat is fully deduped/in-queue-masked above, so the merge can
-            # skip _dedupe_new's O((WM)² + WM·L) re-derivation per step
-            merged, ranks = jax.vmap(
-                lambda qq, d, i: qmod.merge_expand(qq, d, i, W))(
-                    queue, nd, flat)
-        queue = jax.tree.map(
-            lambda new, old: jnp.where(
-                exp_any.reshape((Q,) + (1,) * (new.ndim - 1)), new, old),
-            merged, queue)
+            # --- the 1-to-B (here Q-to-W·B) batched distance step (H1 +
+            # H2) ---
+            if use_fused:
+                sd, si, bests, ties = expand_fn(data, queries, flat)
+            else:
+                nd = _dist_chunked(dist_fn, data, queries, flat,
+                                   cfg.batch_B)
+                nd = jnp.where(flat >= 0, nd, jnp.inf)
 
-        # --- early termination, Eq. 3: per expansion, in beam order ---
-        beyond = ranks > t_pos                             # (Q, W)
-        et_ctr, fired = c.et_ctr, c.et_fired
-        for w in range(W):
-            ex = exp_w[:, w]
-            et_ctr = jnp.where(ex, jnp.where(beyond[:, w], et_ctr + 1, 0),
-                               et_ctr)
-            fired = fired | (cfg.early_term & ex
-                             & (et_ctr >= cfg.et_patience))
+        with jax.named_scope("kbest.queue"):
+            if use_fused:
+                merged = jax.vmap(qmod.merge_sorted_runs)(queue, sd, si)
+                ranks = jax.vmap(qmod.block_ranks)(queue, sd, bests, ties)
+            else:
+                # flat is fully deduped/in-queue-masked above, so the merge
+                # can skip _dedupe_new's O((WM)² + WM·L) re-derivation per
+                # step
+                merged, ranks = jax.vmap(
+                    lambda qq, d, i: qmod.merge_expand(qq, d, i, W))(
+                        queue, nd, flat)
+            queue = jax.tree.map(
+                lambda new, old: jnp.where(
+                    exp_any.reshape((Q,) + (1,) * (new.ndim - 1)), new, old),
+                merged, queue)
 
-        hops = c.hops + jnp.sum(exp_w, axis=1).astype(jnp.int32)
-        ndist = c.ndist + jnp.where(exp_any, n_new, 0)
-        active = c.active & any_has & ~fired & (hops < cfg.hops_bound)
+            # --- early termination, Eq. 3: per expansion, in beam order ---
+            beyond = ranks > t_pos                             # (Q, W)
+            et_ctr, fired = c.et_ctr, c.et_fired
+            for w in range(W):
+                ex = exp_w[:, w]
+                et_ctr = jnp.where(ex, jnp.where(beyond[:, w], et_ctr + 1,
+                                                 0), et_ctr)
+                fired = fired | (cfg.early_term & ex
+                                 & (et_ctr >= cfg.et_patience))
+
+            hops = c.hops + jnp.sum(exp_w, axis=1).astype(jnp.int32)
+            ndist = c.ndist + jnp.where(exp_any, n_new, 0)
+            active = c.active & any_has & ~fired & (hops < cfg.hops_bound)
         return _Carry(queue.dists, queue.ids, queue.visited, bitmap,
                       et_ctr, fired, active, hops, ndist, c.it + 1)
 
     out = jax.lax.while_loop(cond, body, carry)
     final = qmod.Queue(out.dists, out.ids, out.visited)
-    dists_k, ids_k = jax.vmap(lambda q: qmod.topk(q, k))(final)
+    with jax.named_scope("kbest.queue"):
+        dists_k, ids_k = jax.vmap(lambda q: qmod.topk(q, k))(final)
     stats = SearchStats(out.hops, out.ndist, out.et_fired, out.it)
     return dists_k, ids_k, stats
 

@@ -25,23 +25,39 @@ than the search itself. The engine removes that cost structurally:
      actual traces (a Python side effect at trace time), which is both the
      serving telemetry and the regression guard: serving many batch sizes
      under one bucket must trace exactly once.
-  4. **Telemetry.** Each call records wall latency, per-query distance
-     counts, and early-termination fires; `stats()` folds them into an
-     `EngineStats` snapshot (p50/p95/p99, dists/query, ET fire rate, and
-     recall when ground truth is supplied).
+  4. **Telemetry.** Each dispatch records wall latency, per-query
+     distance counts, and early-termination fires; `stats()` folds them
+     into an `EngineStats` snapshot (p50/p95/p99, dists/query, ET fire
+     rate, and recall when ground truth is supplied).
+  5. **Spans and counters** (`serve.spans`). Each bucket dispatch is
+     timed in spans `kbest.pad`, `kbest.enqueue` (the jit call: argument
+     flattening, host-to-device copy, launch), `kbest.device_wait`
+     (`block_until_ready`) and `kbest.fetch` (one batched device-to-host
+     copy of results and counters); `serve_loop` wraps each `search` call
+     in a `kbest.dispatch` span labelled with its requests. The compiled
+     program sums its lanes' SearchStats into one small int32 vector, so
+     the counters (rows true and padded, distances, early terminations,
+     lockstep iterations, hops) cost no extra transfer. Each bucket's
+     program is named `kbest_search_b<bucket>`, and its stages carry
+     `kbest.*` named scopes (`compiled_text` shows them in the HLO).
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.index import KBest
 from repro.core.types import SearchConfig
+from repro.serve.spans import Recorder, SpanStats
+
+# the order of the per-dispatch counter vector the compiled program returns:
+# SearchStats summed over the live lanes (iters is the batch's own count)
+DEVICE_COUNTERS = ("n_dist", "early_terminated", "iters", "hops")
 
 
 def bucket_for(q: int, min_bucket: int = 8, max_bucket: int = 256) -> int:
@@ -95,6 +111,17 @@ class EngineStats:
     n_failed: int = 0          # dispatch raised; failed its own result
     deadline_miss_rate: float = 0.0   # served-late / deadline-carrying
     degrade_occupancy: Tuple[Tuple[int, int], ...] = ()  # (level, dispatches)
+    # ---- spans and counters (serve.spans), summed over dispatches ----
+    host_p50_ms: float = 0.0   # median host time of a bucket dispatch:
+                               # kbest.pad through kbest.fetch, less
+                               # kbest.device_wait
+    rows_padded: int = 0       # padding lanes added to reach the bucket
+                               # (the live lanes are n_queries)
+    iters: int = 0             # lockstep iterations (0 for IVF)
+    hops: int = 0              # nodes expanded by the live lanes
+    lane_slots: int = 0        # iters x live lanes x beam width: the
+                               # expansions the lockstep loop had room for
+    spans: Dict[str, SpanStats] = dataclasses.field(default_factory=dict)
 
     def summary(self) -> str:
         rec = ("-" if self.recall_at_k is None
@@ -134,9 +161,8 @@ class SearchEngine:
         self.cache_hits = 0
         self.cache_misses = 0
         self._lat_ms: list = []
-        self._n_queries = 0
-        self._sum_dists = 0
-        self._sum_et = 0
+        self._host_ms: list = []
+        self.recorder = Recorder()
         self._gt_hits = 0.0
         self._gt_queries = 0
         # overload telemetry (DESIGN.md §17), fed by serve_loop's note_*
@@ -170,9 +196,18 @@ class SearchEngine:
                 # Python side effect: executes once per XLA trace, never on
                 # cached executions — this IS the trace counter.
                 self.n_traces += 1
-                return index.search_padded(q, mask, search_cfg=scfg,
-                                           with_stats=True)
+                dists, ids, st = index.search_padded(
+                    q, mask, search_cfg=scfg, with_stats=True)
+                # padded lanes come back with zeroed stats, so these sums
+                # are over the live lanes
+                counters = jnp.stack([
+                    jnp.sum(st.n_dist), jnp.sum(st.early_terminated),
+                    st.iters, jnp.sum(st.n_hops)]).astype(jnp.int32)
+                return dists, ids, counters
 
+            # a stable program name per bucket: trace ops then read
+            # jit_kbest_search_b256(...)/%fusion.52
+            run.__name__ = run.__qualname__ = f"kbest_search_b{bucket}"
             # the index is an ARGUMENT (KBest/ShardedKBest are pytrees):
             # its arrays stay where they are on the device and the program
             # reads them as operands instead of embedding them as constants
@@ -203,6 +238,20 @@ class SearchEngine:
             jax.block_until_ready(out)
         return self.n_traces - before
 
+    def compiled_text(self, bucket: int, k: Optional[int] = None,
+                      search_cfg: Optional[SearchConfig] = None) -> str:
+        """The optimized HLO of a warmed bucket's program. Its
+        `metadata={op_name=...}` carries the `kbest.*` scopes, which the
+        device ops of a profiler trace do not: this text maps op to scope.
+        Neither traces nor compiles again, and leaves the telemetry as it
+        is; a bucket not warmed raises KeyError."""
+        scfg = self.index._resolve_cfg(k, search_cfg)
+        fn = self._cache[self._cache_key(bucket, scfg)]
+        d = self.index.db.shape[1]
+        lowered = fn.func.lower(self.index, np.zeros((bucket, d), np.float32),
+                                np.zeros((bucket,), bool))
+        return lowered.compile().as_text()
+
     # -------------------------------------------------------------- search
     def search(self, queries: np.ndarray, k: Optional[int] = None,
                search_cfg: Optional[SearchConfig] = None,
@@ -230,24 +279,32 @@ class SearchEngine:
                     np.concatenate([p[1] for p in parts]))
 
         bucket = bucket_for(Q, self.min_bucket, self.max_bucket)
-        qp = np.zeros((bucket, queries.shape[1]), np.float32)
-        qp[:Q] = queries
-        mask = np.zeros((bucket,), bool)
-        mask[:Q] = True
-
+        rec = self.recorder
+        with rec.span("kbest.pad") as pad:
+            qp = np.zeros((bucket, queries.shape[1]), np.float32)
+            qp[:Q] = queries
+            mask = np.zeros((bucket,), bool)
+            mask[:Q] = True
         fn = self._compiled(bucket, scfg)
-        t0 = time.perf_counter()
-        dists, ids, stats = fn(qp, mask)
-        jax.block_until_ready((dists, ids))
-        dt_ms = (time.perf_counter() - t0) * 1e3
+        with rec.span("kbest.enqueue") as enqueue:
+            out = fn(qp, mask)
+        with rec.span("kbest.device_wait") as wait:
+            jax.block_until_ready(out)
+        with rec.span("kbest.fetch") as fetch:
+            # one batched copy: results and the counter vector
+            dists, ids, counters = jax.device_get(out)
+            dists, ids = dists[:Q], ids[:Q]
+            got = dict(zip(DEVICE_COUNTERS, counters.tolist()))
+            for name, v in got.items():
+                rec.count(name, v)
+            rec.count("rows_true", Q)
+            rec.count("rows_padded", bucket - Q)
+            rec.count("lane_slots", got["iters"] * Q * scfg.beam_width)
+        # the dispatch latency: the jit call through block_until_ready; the
+        # host's part: padding through the fetch, less the device wait
+        self._lat_ms.append((wait.end_ns - enqueue.start_ns) * 1e-6)
+        self._host_ms.append((fetch.end_ns - pad.start_ns) * 1e-6 - wait.ms)
 
-        self._lat_ms.append(dt_ms)
-        self._n_queries += Q
-        self._sum_dists += int(np.asarray(stats.n_dist).sum())
-        self._sum_et += int(np.asarray(stats.early_terminated).sum())
-
-        dists = np.asarray(dists)[:Q]
-        ids = np.asarray(ids)[:Q]
         if gt_ids is not None:
             from repro.data.vectors import recall_at_k
             self._gt_hits += recall_at_k(ids, np.asarray(gt_ids)[:Q],
@@ -277,10 +334,12 @@ class SearchEngine:
     def stats(self) -> EngineStats:
         p50, p95, p99 = percentiles(self._lat_ms)
         lat = np.asarray(self._lat_ms, np.float64)
-        nq = max(self._n_queries, 1)
+        c = self.recorder.counters
+        n_queries = c.get("rows_true", 0)
+        nq = max(n_queries, 1)
         return EngineStats(
             n_requests=lat.size,
-            n_queries=self._n_queries,
+            n_queries=n_queries,
             n_traces=self.n_traces,
             cache_hits=self.cache_hits,
             cache_misses=self.cache_misses,
@@ -288,8 +347,8 @@ class SearchEngine:
             lat_p95_ms=p95,
             lat_p99_ms=p99,
             mean_lat_ms=float(lat.mean()) if lat.size else 0.0,
-            dists_per_query=self._sum_dists / nq,
-            et_fire_rate=self._sum_et / nq,
+            dists_per_query=c.get("n_dist", 0) / nq,
+            et_fire_rate=c.get("early_terminated", 0) / nq,
             recall_at_k=(self._gt_hits / self._gt_queries
                          if self._gt_queries else None),
             n_rejected=self._n_rejected,
@@ -298,15 +357,20 @@ class SearchEngine:
             deadline_miss_rate=(self._n_deadline_missed / self._n_deadline
                                 if self._n_deadline else 0.0),
             degrade_occupancy=tuple(sorted(self._degrade_occ.items())),
+            host_p50_ms=percentiles(self._host_ms)[0],
+            rows_padded=c.get("rows_padded", 0),
+            iters=c.get("iters", 0),
+            hops=c.get("hops", 0),
+            lane_slots=c.get("lane_slots", 0),
+            spans=self.recorder.spans(),
         )
 
     def reset_stats(self) -> None:
         """Clear telemetry; the compile cache (and n_traces) is kept —
         traces are a property of the cache, not of a measurement window."""
         self._lat_ms = []
-        self._n_queries = 0
-        self._sum_dists = 0
-        self._sum_et = 0
+        self._host_ms = []
+        self.recorder.reset()
         self._gt_hits = 0.0
         self._gt_queries = 0
         self._n_rejected = 0

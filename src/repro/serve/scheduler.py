@@ -43,7 +43,6 @@ cannot change any outcome.
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections import deque
 from typing import Dict, List, Optional, Sequence, Union
 
@@ -51,7 +50,8 @@ import numpy as np
 
 from repro.core.types import SearchConfig
 from repro.serve.degrade import DegradePolicy, LatencyModel
-from repro.serve.engine import EngineStats, SearchEngine, percentiles
+from repro.serve.engine import (EngineStats, SearchEngine, bucket_for,
+                                percentiles)
 from repro.serve.faults import FaultInjector
 
 # RequestResult.status codes
@@ -165,9 +165,14 @@ def _dispatch(eng: SearchEngine, group: List[Request], scfg: SearchConfig,
             gt = np.concatenate([np.asarray(g) for g in gts], axis=0)
     if faults is not None:
         faults.check(group)
-    t0 = time.perf_counter()
-    dists, ids = eng.search(batch, search_cfg=scfg, gt_ids=gt)
-    dt_ms = (time.perf_counter() - t0) * 1e3
+    # spans of one request share its id; dt_ms is this span's time
+    with eng.recorder.span(
+            "kbest.dispatch", engine=eng.name,
+            bucket=bucket_for(len(batch), eng.min_bucket, eng.max_bucket),
+            rows=len(batch), first_request_id=group[0].request_id,
+            n_requests=len(group)) as span:
+        dists, ids = eng.search(batch, search_cfg=scfg, gt_ids=gt)
+    dt_ms = span.ms
     if faults is not None:
         dt_ms += faults.extra_ms(group)
     return dists, ids, dt_ms
@@ -195,162 +200,170 @@ def serve_loop(engines: Union[SearchEngine, Dict[str, SearchEngine]],
     """
     if isinstance(engines, SearchEngine):
         engines = {engines.name: engines}
-    skew = faults.skew_ms if faults is not None else 0.0
+    # the scheduler's own time (admission, coalescing, slicing results
+    # back) is this span's self time; the first engine's recorder holds it
+    first = next(iter(engines.values()))
+    with first.recorder.span("kbest.serve_loop", n_requests=len(requests)):
+        skew = faults.skew_ms if faults is not None else 0.0
 
-    def arr(r: Request) -> float:
-        return r.arrival_ms + skew
+        def arr(r: Request) -> float:
+            return r.arrival_ms + skew
 
-    admission_on = (any(r.deadline_ms > 0 for r in requests)
-                    if admission is None else bool(admission))
-    model = latency_model
-    if model is None and admission_on:
-        model = LatencyModel()
+        admission_on = (any(r.deadline_ms > 0 for r in requests)
+                        if admission is None else bool(admission))
+        model = latency_model
+        if model is None and admission_on:
+            model = LatencyModel()
 
-    q = deque(requests)
-    results: List[RequestResult] = []
-    finishes: List[float] = []        # virtual finish times of served reqs
-    next_id = 0
-    n_dispatches = 0
-    t_free = 0.0
+        q = deque(requests)
+        results: List[RequestResult] = []
+        finishes: List[float] = []        # virtual finish times of served reqs
+        next_id = 0
+        n_dispatches = 0
+        t_free = 0.0
 
-    def assign_id(r: Request) -> Request:
-        nonlocal next_id
-        if r.request_id < 0:
-            r.request_id = next_id
-        next_id = max(next_id, r.request_id) + 1
-        return r
+        def assign_id(r: Request) -> Request:
+            nonlocal next_id
+            if r.request_id < 0:
+                r.request_id = next_id
+            next_id = max(next_id, r.request_id) + 1
+            return r
 
-    def record_served(group, dists, ids, dt_ms, start, scfg, eng, level):
-        """Slice a successful dispatch back per request; returns finish."""
-        finish = start + dt_ms
-        s = 0
-        for r in group:
-            e = s + r.n_queries
-            rec = None
-            if r.gt_ids is not None:
-                from repro.data.vectors import recall_at_k
-                rec = recall_at_k(ids[s:e], np.asarray(r.gt_ids), scfg.k)
-            sojourn = finish - arr(r)
-            missed = r.deadline_ms > 0 and sojourn > r.deadline_ms
-            if r.deadline_ms > 0:
-                eng.note_deadline(missed)
-            results.append(RequestResult(
-                request_id=r.request_id, engine=r.engine,
-                dists=dists[s:e], ids=ids[s:e], n_served=r.n_queries,
-                latency_ms=dt_ms, recall=rec, status=STATUS_OK,
-                queue_delay_ms=start - arr(r), sojourn_ms=sojourn,
-                deadline_missed=missed, degrade_level=level))
-            finishes.append(finish)
-            s = e
-        if degrade is not None:
-            eng.note_degrade(level)
-        if model is not None:
-            model.observe(eng, scfg, sum(r.n_queries for r in group), dt_ms)
-        return finish
+        def record_served(group, dists, ids, dt_ms, start, scfg, eng, level):
+            """Slice a successful dispatch back per request; returns finish."""
+            finish = start + dt_ms
+            s = 0
+            for r in group:
+                e = s + r.n_queries
+                rec = None
+                if r.gt_ids is not None:
+                    from repro.data.vectors import recall_at_k
+                    rec = recall_at_k(ids[s:e], np.asarray(r.gt_ids), scfg.k)
+                sojourn = finish - arr(r)
+                missed = r.deadline_ms > 0 and sojourn > r.deadline_ms
+                if r.deadline_ms > 0:
+                    eng.note_deadline(missed)
+                results.append(RequestResult(
+                    request_id=r.request_id, engine=r.engine,
+                    dists=dists[s:e], ids=ids[s:e], n_served=r.n_queries,
+                    latency_ms=dt_ms, recall=rec, status=STATUS_OK,
+                    queue_delay_ms=start - arr(r), sojourn_ms=sojourn,
+                    deadline_missed=missed, degrade_level=level))
+                finishes.append(finish)
+                s = e
+            if degrade is not None:
+                eng.note_degrade(level)
+            if model is not None:
+                model.observe(eng, scfg, sum(r.n_queries for r in group),
+                              dt_ms)
+            return finish
 
-    while q:
-        r0 = assign_id(q.popleft())
-        eng = engines[r0.engine]
-        key = _coalesce_key(engines, r0)
-        base_cfg: SearchConfig = key[1]
-        a0 = arr(r0)
-        start = max(t_free, a0)
+        while q:
+            r0 = assign_id(q.popleft())
+            eng = engines[r0.engine]
+            key = _coalesce_key(engines, r0)
+            base_cfg: SearchConfig = key[1]
+            a0 = arr(r0)
+            start = max(t_free, a0)
 
-        # ---- bounded queue: shed an arrival that finds it full
-        if max_queue > 0 and \
-                sum(1 for f in finishes if f > a0) >= max_queue:
-            results.append(_not_served(r0, base_cfg.k, STATUS_SHED))
-            eng.note_shed()
-            continue
-
-        # ---- degradation: observe load, pick the rung this dispatch serves
-        level = 0
-        scfg = base_cfg
-        if degrade is not None:
-            level = degrade.observe(start - a0)
-            scfg = degrade.apply(base_cfg)
-
-        # ---- admission: reject a deadline the predicted finish busts
-        if admission_on and r0.deadline_ms > 0:
-            pred = model.slack * model.predict_ms(eng, scfg, r0.n_queries)
-            if start + pred > a0 + r0.deadline_ms:
-                results.append(_not_served(
-                    r0, base_cfg.k, STATUS_REJECTED,
-                    queue_delay_ms=start - a0))
-                eng.note_rejected()
+            # ---- bounded queue: shed an arrival that finds it full
+            if max_queue > 0 and \
+                    sum(1 for f in finishes if f > a0) >= max_queue:
+                results.append(_not_served(r0, base_cfg.k, STATUS_SHED))
+                eng.note_shed()
                 continue
 
-        group = [r0]
-        rows = r0.n_queries
-        while (coalesce and q and rows < eng.max_bucket
-               and _coalesce_key(engines, q[0]) == key
-               and rows + q[0].n_queries <= eng.max_bucket
-               and arr(q[0]) <= start):
-            r = assign_id(q.popleft())
-            if admission_on and r.deadline_ms > 0:
-                pred = model.slack * model.predict_ms(eng, scfg, r.n_queries)
-                if start + pred > arr(r) + r.deadline_ms:
+            # ---- degradation: observe load, pick the rung this dispatch
+            # serves
+            level = 0
+            scfg = base_cfg
+            if degrade is not None:
+                level = degrade.observe(start - a0)
+                scfg = degrade.apply(base_cfg)
+
+            # ---- admission: reject a deadline the predicted finish busts
+            if admission_on and r0.deadline_ms > 0:
+                pred = model.slack * model.predict_ms(eng, scfg, r0.n_queries)
+                if start + pred > a0 + r0.deadline_ms:
                     results.append(_not_served(
-                        r, base_cfg.k, STATUS_REJECTED,
-                        queue_delay_ms=start - arr(r)))
+                        r0, base_cfg.k, STATUS_REJECTED,
+                        queue_delay_ms=start - a0))
                     eng.note_rejected()
                     continue
-            rows += r.n_queries
-            group.append(r)
 
-        try:
-            dists, ids, dt_ms = _dispatch(eng, group, scfg, faults)
-        except Exception as exc:                      # ---- error boundary
-            if len(group) == 1:
-                results.append(_not_served(
-                    r0, base_cfg.k, STATUS_FAILED, error=repr(exc),
-                    queue_delay_ms=start - a0))
-                eng.note_failed()
-                continue          # a failed dispatch charges no service time
-            # un-coalesce: re-dispatch singly so only the poisoned
-            # request(s) fail — the group must not share their fate
-            t = start
-            for r in group:
-                try:
-                    d1, i1, one_ms = _dispatch(eng, [r], scfg, faults)
-                except Exception as exc1:
+            group = [r0]
+            rows = r0.n_queries
+            while (coalesce and q and rows < eng.max_bucket
+                   and _coalesce_key(engines, q[0]) == key
+                   and rows + q[0].n_queries <= eng.max_bucket
+                   and arr(q[0]) <= start):
+                r = assign_id(q.popleft())
+                if admission_on and r.deadline_ms > 0:
+                    pred = model.slack * model.predict_ms(eng, scfg,
+                                                          r.n_queries)
+                    if start + pred > arr(r) + r.deadline_ms:
+                        results.append(_not_served(
+                            r, base_cfg.k, STATUS_REJECTED,
+                            queue_delay_ms=start - arr(r)))
+                        eng.note_rejected()
+                        continue
+                rows += r.n_queries
+                group.append(r)
+
+            try:
+                dists, ids, dt_ms = _dispatch(eng, group, scfg, faults)
+            except Exception as exc:                      # ---- error boundary
+                if len(group) == 1:
                     results.append(_not_served(
-                        r, base_cfg.k, STATUS_FAILED, error=repr(exc1),
-                        queue_delay_ms=t - arr(r)))
+                        r0, base_cfg.k, STATUS_FAILED, error=repr(exc),
+                        queue_delay_ms=start - a0))
                     eng.note_failed()
-                    continue
-                n_dispatches += 1
-                t = record_served([r], d1, i1, one_ms, t, scfg, eng, level)
-            t_free = max(t_free, t)
-            continue
+                    continue    # a failed dispatch charges no service time
+                # un-coalesce: re-dispatch singly so only the poisoned
+                # request(s) fail — the group must not share their fate
+                t = start
+                for r in group:
+                    try:
+                        d1, i1, one_ms = _dispatch(eng, [r], scfg, faults)
+                    except Exception as exc1:
+                        results.append(_not_served(
+                            r, base_cfg.k, STATUS_FAILED, error=repr(exc1),
+                            queue_delay_ms=t - arr(r)))
+                        eng.note_failed()
+                        continue
+                    n_dispatches += 1
+                    t = record_served([r], d1, i1, one_ms, t, scfg, eng, level)
+                t_free = max(t_free, t)
+                continue
 
-        n_dispatches += 1
-        t_free = record_served(group, dists, ids, dt_ms, start, scfg, eng,
-                               level)
+            n_dispatches += 1
+            t_free = record_served(group, dists, ids, dt_ms, start, scfg, eng,
+                                   level)
 
-    served = [r for r in results if r.status == STATUS_OK]
-    n_served = sum(r.n_served for r in served)
-    with_gt = [(r.recall, r.n_served) for r in served if r.recall is not None]
-    recall = (sum(rc * ns for rc, ns in with_gt)
-              / max(sum(ns for _, ns in with_gt), 1)) if with_gt else None
-    lat_p50, lat_p95, lat_p99 = percentiles([r.latency_ms for r in served])
-    soj_p50, soj_p95, soj_p99 = percentiles([r.sojourn_ms for r in served])
-    return ServeReport(
-        results=results,
-        n_requests=len(results),
-        n_served=n_served,
-        n_dispatches=n_dispatches,
-        recall_at_k=recall,
-        lat_p50_ms=lat_p50,
-        lat_p95_ms=lat_p95,
-        lat_p99_ms=lat_p99,
-        engine_stats={name: e.stats() for name, e in engines.items()},
-        n_rejected=sum(r.status == STATUS_REJECTED for r in results),
-        n_shed=sum(r.status == STATUS_SHED for r in results),
-        n_failed=sum(r.status == STATUS_FAILED for r in results),
-        n_deadline_missed=sum(r.deadline_missed for r in results),
-        sojourn_p50_ms=soj_p50,
-        sojourn_p95_ms=soj_p95,
-        sojourn_p99_ms=soj_p99,
-        t_end_ms=max(finishes) if finishes else 0.0,
-    )
+        served = [r for r in results if r.status == STATUS_OK]
+        n_served = sum(r.n_served for r in served)
+        with_gt = [(r.recall, r.n_served) for r in served
+                   if r.recall is not None]
+        recall = (sum(rc * ns for rc, ns in with_gt)
+                  / max(sum(ns for _, ns in with_gt), 1)) if with_gt else None
+        lat_p50, lat_p95, lat_p99 = percentiles([r.latency_ms for r in served])
+        soj_p50, soj_p95, soj_p99 = percentiles([r.sojourn_ms for r in served])
+        return ServeReport(
+            results=results,
+            n_requests=len(results),
+            n_served=n_served,
+            n_dispatches=n_dispatches,
+            recall_at_k=recall,
+            lat_p50_ms=lat_p50,
+            lat_p95_ms=lat_p95,
+            lat_p99_ms=lat_p99,
+            engine_stats={name: e.stats() for name, e in engines.items()},
+            n_rejected=sum(r.status == STATUS_REJECTED for r in results),
+            n_shed=sum(r.status == STATUS_SHED for r in results),
+            n_failed=sum(r.status == STATUS_FAILED for r in results),
+            n_deadline_missed=sum(r.deadline_missed for r in results),
+            sojourn_p50_ms=soj_p50,
+            sojourn_p95_ms=soj_p95,
+            sojourn_p99_ms=soj_p99,
+            t_end_ms=max(finishes) if finishes else 0.0,
+        )
